@@ -30,6 +30,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.cluster import ReplicaGroup
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, smoke_config
 from repro.memory import PAPER_POLICIES
 from repro.models import Model
@@ -132,6 +133,7 @@ def run(policies=PAPER_POLICIES, replica_counts=(1, 2, 4),
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--policies", default="",
                     help="comma-separated policy names (default: the "

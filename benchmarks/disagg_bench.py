@@ -54,6 +54,7 @@ from collections import deque
 import numpy as np
 
 from repro.cluster import LifecycleManager, ReplicaGroup
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, smoke_config
 from repro.memory import PAPER_POLICIES
 from repro.models import Model
@@ -416,6 +417,7 @@ def bench_kill(model, policies, *, heartbeat_timeout, write_json):
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="stamp-it-only quick pass for CI; never writes "

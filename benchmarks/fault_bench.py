@@ -39,6 +39,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.cluster import LifecycleManager, ReplicaGroup
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, smoke_config
 from repro.memory import PAPER_POLICIES
 from repro.models import Model
@@ -223,6 +224,7 @@ def run(policies=PAPER_POLICIES, n_replicas=4, requests=24, max_new=10,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--policies", default="",
                     help="comma-separated policy names "

@@ -41,6 +41,7 @@ from pathlib import Path
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, smoke_config
 from repro.memory import PAPER_POLICIES, POLICIES
 from repro.models import Model
@@ -592,6 +593,7 @@ def _update_json(policies=None, sweep=None, long_prompt=None,
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sweep", default="",
                     help='scaling axes, e.g. "pipeline_depth,slots" '

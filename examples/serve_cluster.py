@@ -34,10 +34,12 @@ from repro.cluster import (
 )
 from repro.memory import POLICIES
 from repro.models import Model
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, smoke_config
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--policy", default="stamp-it",

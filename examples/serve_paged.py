@@ -14,8 +14,10 @@ dispatch with the truncated-model speculative lane (greedy only).
 import argparse
 import time
 
+import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, smoke_config
 from repro.memory import POLICIES
 from repro.models import Model
@@ -23,6 +25,7 @@ from repro.serving import ServingEngine
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="stamp-it",
                     choices=sorted(POLICIES))
@@ -77,9 +80,10 @@ def main() -> None:
     eng.drain()
 
     toks = sum(len(r.generated) for r in done)
+    dev = jax.devices()[0]
     print(f"policy={args.policy}  requests={len(done)}  "
           f"generated={toks} tokens in {dt:.2f}s "
-          f"({toks/dt:.1f} tok/s on CPU)")
+          f"({toks/dt:.1f} tok/s on {dev.platform} {dev.device_kind})")
     for r in done[:4]:
         print(f"  req {r.rid}: prompt[{len(r.prompt)}] -> {r.generated}")
     s = eng.stats()

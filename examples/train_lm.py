@@ -8,6 +8,7 @@ pipeline, async checkpointing, simulated failure + restart.
 import argparse
 import tempfile
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS, ShapeConfig
 from repro.launch.mesh import make_debug_mesh
 from repro.models import Model
@@ -15,6 +16,7 @@ from repro.training import AdamWConfig, Trainer, inject_failure_at
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--inject-failure", type=int, default=-1)

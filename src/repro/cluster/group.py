@@ -31,7 +31,10 @@ engines are never renumbered, husks stay in ``engines`` with
 
 Params are shared: all replicas serve the same model, so ONE param tree
 is built and passed to every engine (device arrays for KV state stay
-per-replica).
+per-replica).  With ``devices``, replica ``i`` runs on
+``devices[i % len(devices)]``: its engine commits its own copy of the
+params, its KV pool and its step operands there (one replica per chip,
+one host process); cross-replica moves go through host memory.
 
 Disaggregated mode (``prefill_replicas=P, decode_replicas=D``) splits
 the replicas into a prefill tier and a decode tier joined by a
@@ -85,6 +88,7 @@ class ReplicaGroup:
         prefill_chunk_tokens: Optional[int] = None,
         handoff_import_delay: int = 0,
         registry: Optional[Registry] = None,
+        devices: Optional[Sequence[Any]] = None,
     ) -> None:
         # disaggregated mode: replicas 0..P-1 form the prefill tier,
         # P..P+D-1 the decode tier (n_replicas is derived, not taken)
@@ -116,6 +120,7 @@ class ReplicaGroup:
         self.spans = SpanRecorder(enabled=self.obs.enabled)
         self.shards = ShardedPoolSet(n_replicas)
         self._params = model.init_params(seed)
+        self._devices = list(devices) if devices else None
         self._sample_seed = sample_seed
         # engine kwargs, kept so add_replica() builds IDENTICAL replicas
         self._engine_kw: Dict[str, Any] = dict(
@@ -199,6 +204,8 @@ class ReplicaGroup:
             journal=RequestJournal(i),
             registry=self.obs,
             spans=self.spans,
+            device=(self._devices[i % len(self._devices)]
+                    if self._devices else None),
         )
 
     @property

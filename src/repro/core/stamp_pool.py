@@ -194,14 +194,11 @@ class StampPool:
         if next_prev.mark & DELETE_MARK:
             self._mark_next(next_.obj, next_stamp)
             if last.obj is not None:
-                # last should be next's predecessor: unlink next.
-                last_prev = last.obj.prev.load()
-                if last_prev.obj is next_.obj and not (
-                    last_prev.mark & DELETE_MARK
-                ):
-                    last.obj.prev.compare_exchange(
-                        last_prev, next_prev.obj, 0
-                    )
+                # next_ is last.prev exactly as read when we stepped from
+                # last to next: the CAS unlinks next only if that word is
+                # unchanged (its tag defeats ABA — next may have been
+                # removed and re-pushed since).
+                last.obj.prev.compare_exchange(next_, next_prev.obj, 0)
                 return True, last, _NULL
             return True, next_.obj.next.load().clear_mark(), last
         return False, next_, last

@@ -1,7 +1,7 @@
 """Pallas TPU kernel: KV-page gather / compaction.
 
 The BlockPool reclaimer's defrag/compaction hot path: copy M pages (page =
-(block, Hkv, D)) selected by an index vector out of a pool.  The page ids
+(Hkv, block, D)) selected by an index vector out of a pool.  The page ids
 drive the input index_map via scalar prefetch — a pure HBM->HBM streaming
 copy through VMEM with zero wasted traffic.
 """
@@ -19,12 +19,12 @@ def _kernel(idx_ref, pool_ref, out_ref):
 
 
 def block_gather_pallas(
-    pool: jax.Array,     # (N_pool, block, Hkv, D)
+    pool: jax.Array,     # (N_pool, Hkv, block, D)
     indices: jax.Array,  # (M,) int32
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    n_pool, block, hkv, d = pool.shape
+    n_pool, hkv, block, d = pool.shape
     m = indices.shape[0]
     return pl.pallas_call(
         _kernel,
@@ -32,12 +32,12 @@ def block_gather_pallas(
             num_scalar_prefetch=1,
             grid=(m,),
             in_specs=[
-                pl.BlockSpec((1, block, hkv, d),
+                pl.BlockSpec((1, hkv, block, d),
                              lambda i, idx: (idx[i], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block, hkv, d),
+            out_specs=pl.BlockSpec((1, hkv, block, d),
                                    lambda i, idx: (i, 0, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((m, block, hkv, d), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, hkv, block, d), pool.dtype),
         interpret=interpret,
     )(indices, pool)

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import axis_size, shard_map
+from .ref import from_pages
+
 
 NEG_INF = -1e30
 
@@ -62,7 +63,6 @@ def _partial_flash(q, k, v, pos, valid):
 def _shard_body(q, k_loc, v_loc, table, lengths, k1, v1, *,
                 axis: str, block: int):
     idx = jax.lax.axis_index(axis)
-    n_shards = axis_size(axis)
     B = q.shape[0]
     mb_loc = k_loc.shape[1]
     barange = jnp.arange(B)
@@ -73,12 +73,12 @@ def _shard_body(q, k_loc, v_loc, table, lengths, k1, v1, *,
     own = (local_page >= 0) & (local_page < mb_loc)
     lp = jnp.clip(local_page, 0, mb_loc - 1)
     slot = lengths % block
-    old_k = k_loc[barange, lp, slot]
-    old_v = v_loc[barange, lp, slot]
-    k_loc = k_loc.at[barange, lp, slot].set(
+    old_k = k_loc[barange, lp, :, slot]  # (B, Hkv, D)
+    old_v = v_loc[barange, lp, :, slot]
+    k_loc = k_loc.at[barange, lp, :, slot].set(
         jnp.where(own[:, None, None], k1.astype(k_loc.dtype), old_k)
     )
-    v_loc = v_loc.at[barange, lp, slot].set(
+    v_loc = v_loc.at[barange, lp, :, slot].set(
         jnp.where(own[:, None, None], v1.astype(v_loc.dtype), old_v)
     )
 
@@ -100,8 +100,8 @@ def _shard_body(q, k_loc, v_loc, table, lengths, k1, v1, *,
     pos = inv[:, :, None] * block + offs[None, None, :]   # (B, mb_loc, bl)
     valid = (inv[:, :, None] >= 0) & (pos < (lengths + 1)[:, None, None])
     S_loc = mb_loc * block
-    k_flat = k_loc.reshape(B, S_loc, *k_loc.shape[3:])
-    v_flat = v_loc.reshape(B, S_loc, *v_loc.shape[3:])
+    k_flat = from_pages(k_loc)  # (B, S_loc, Hkv, D)
+    v_flat = from_pages(v_loc)
 
     acc, m, l = _partial_flash(
         q, k_flat, v_flat, pos.reshape(B, S_loc), valid.reshape(B, S_loc)
@@ -118,7 +118,7 @@ def _shard_body(q, k_loc, v_loc, table, lengths, k1, v1, *,
 
 def paged_attention_dist(
     q: jax.Array,        # (B, H, D)  — replicated over `model`
-    k_pool: jax.Array,   # (B, MB, block, Hkv, D) — MB sharded over `model`
+    k_pool: jax.Array,   # (B, MB, Hkv, block, D) — MB sharded over `model`
     v_pool: jax.Array,
     table: jax.Array,    # (B, MB_logical) int32
     lengths: jax.Array,  # (B,)
@@ -129,7 +129,7 @@ def paged_attention_dist(
     batch_part,          # mesh axes carrying the batch dim (or None)
     axis: str = "model",
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    block = k_pool.shape[2]
+    block = k_pool.shape[3]
     bp = batch_part
     pool_spec = P(bp, axis, None, None, None)
     body = functools.partial(_shard_body, axis=axis, block=block)
@@ -137,7 +137,7 @@ def paged_attention_dist(
     other = tuple(a for a in mesh.axis_names
                   if a != axis and a != bp
                   and not (isinstance(bp, tuple) and a in bp))
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -225,7 +225,7 @@ def _moe_body(x, router, wi_gate, wi_up, wo, *, cfg, axis: str):
     out = jnp.zeros((B, S, M), dt).at[b_ix, sorted_tok].add(contrib)
 
     # single reduction of the COMBINED activations
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if S % n == 0 and S > 1:
         return jax.lax.psum_scatter(out, axis, scatter_dimension=1,
                                     tiled=True)
@@ -242,7 +242,7 @@ def moe_block_dist(p, x, cfg, *, mesh: Mesh, batch_part, axis: str = "model"):
     sp = S % n == 0 and S > 1
     body = ft.partial(_moe_body, cfg=cfg, axis=axis)
     bp = batch_part
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -308,7 +308,7 @@ def rolling_attention_dist(q, k_cache, v_cache, lengths, k1, v1, *,
     bp = batch_part
     spec = P(bp, axis, None, None)
     body = functools.partial(_rolling_body, axis=axis, W=W)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(bp, None, None), spec, spec, P(bp),

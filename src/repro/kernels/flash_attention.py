@@ -15,20 +15,17 @@ Grid: (B, H, n_q_blocks, n_kv_blocks) with the last dimension marked
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-            scale: float, causal: bool, window: int, q_offset: int,
+def _kernel(q_off_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+            scale: float, causal: bool, window: int,
             block_q: int, block_k: int, n_kv: int):
     ik = pl.program_id(3)
 
@@ -38,9 +35,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :]  # (bq, D) — storage dtype into the MXU
-    k = k_ref[0, :, 0, :]  # (bk, D)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]  # (bq, D) — storage dtype into the MXU
+    k = k_ref[...]  # (bk, D)
+    v = v_ref[...]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -49,7 +46,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     iq = pl.program_id(2)
     q_pos = (
-        q_offset + iq * block_q
+        q_off_ref[0] + iq * block_q
         + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     )
     kv_pos = (
@@ -63,22 +60,21 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         mask = mask & (kv_pos > q_pos - window)
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
+    m_prev = m_ref[...]  # (bq, 1)
     l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
+    l_ref[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
-    l_ref[...] = l_new
 
     @pl.when(ik == n_kv - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (
-            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[...] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
@@ -89,11 +85,16 @@ def flash_attention_pallas(
     *,
     causal: bool = True,
     window: int = 0,
-    q_offset: int = 0,
+    q_offset=0,  # int or traced int32 scalar (chunked prefill)
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
+    """The kernel runs head-major — ``(bq, D)`` / ``(bk, D)`` tiles, the
+    only per-head block shape the TPU lowering accepts for head counts
+    that are not multiples of 8 — so the wrapper transposes in and out.
+    ``q_offset`` rides in SMEM as a scalar-prefetch operand, so a traced
+    offset compiles to one program for every chunk position."""
     B, S_q, H, D = q.shape
     _, S_kv, Hkv, _ = k.shape
     assert H % Hkv == 0
@@ -108,30 +109,32 @@ def flash_attention_pallas(
 
     kernel = functools.partial(
         _kernel, scale=scale, causal=causal, window=window,
-        q_offset=q_offset, block_q=block_q, block_k=block_k, n_kv=n_kv,
+        block_q=block_q, block_k=block_k, n_kv=n_kv,
     )
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, block_q, D),
+                          lambda b, h, iq, ik, *_: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, D),
+                           lambda b, h, iq, ik, *_, g=group: (b, h // g, ik, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=(B, H, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, D),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, iq, ik, g=group: (b, ik, h // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S_q, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, n_q, n_kv),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, S_q, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v)
+    )(jnp.asarray(q_offset, jnp.int32).reshape(1),
+      q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

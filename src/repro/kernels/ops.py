@@ -1,30 +1,42 @@
 """Dispatching wrappers for the compute hot-spots.
 
 Each op has three execution paths:
-  * ``ref``        — pure-jnp oracle (:mod:`repro.kernels.ref`); also the
-                     XLA path used for dry-run lowering (Mosaic/TPU kernels
-                     cannot lower on the CPU container).
+  * ``ref``        — pure-jnp oracle (:mod:`repro.kernels.ref`); the XLA
+                     path on every backend but the TPU.
   * ``pallas``     — the TPU kernel (``interpret=False``, target hardware).
   * ``interpret``  — the same Pallas kernel body executed in Python on CPU
                      (correctness validation; see tests/test_kernels.py).
 
 Selection: explicit ``impl=`` argument > ``REPRO_KERNEL_IMPL`` env var >
-default ``ref``.
+the backend: ``pallas`` on a TPU, ``ref`` elsewhere (Mosaic kernels lower
+for the TPU only).  ``interpret`` runs only when asked for by name, and
+an unknown name raises.  The SSD scan kernel does not lower for the TPU
+yet, so that op keeps ``ref`` unless asked by name.
 """
 
 from __future__ import annotations
 
+import functools
 import os
-from functools import partial
 from typing import Optional
 
 import jax
+import jax.numpy as jnp
 
 from . import ref
 
+IMPLS = ("ref", "pallas", "interpret")
 
-def _impl(impl: Optional[str]) -> str:
-    return impl or os.environ.get("REPRO_KERNEL_IMPL", "ref")
+
+def _impl(impl: Optional[str], *, tpu_kernel: bool = True) -> str:
+    mode = impl or os.environ.get("REPRO_KERNEL_IMPL")
+    if not mode:
+        on_tpu = tpu_kernel and jax.default_backend() == "tpu"
+        mode = "pallas" if on_tpu else "ref"
+    if mode not in IMPLS:
+        raise ValueError(f"unknown kernel impl {mode!r}; expected one of "
+                         f"{IMPLS}")
+    return mode
 
 
 # ---------------------------------------------------------------------------
@@ -72,12 +84,37 @@ def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,
             q, k, v, causal=causal, window=window, q_offset=q_offset,
             chunk=chunk,
         )
+    return _flash_pallas(q, k, v, jnp.asarray(q_offset, jnp.int32), causal,
+                         window, mode == "interpret")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_pallas(q, k, v, q_offset, causal, window, interpret):
+    """Pallas forward; the backward pass differentiates the jnp oracle
+    (pallas_call has no transpose rule), so training graphs stay
+    differentiable when the kernel is selected."""
     from .flash_attention import flash_attention_pallas
 
-    return flash_attention_pallas(
-        q, k, v, causal=causal, window=window, q_offset=q_offset,
-        interpret=(mode == "interpret"),
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  q_offset=q_offset, interpret=interpret)
+
+
+def _flash_fwd(q, k, v, q_offset, causal, window, interpret):
+    out = _flash_pallas(q, k, v, q_offset, causal, window, interpret)
+    return out, (q, k, v, q_offset)
+
+
+def _flash_bwd(causal, window, interpret, res, g):
+    q, k, v, q_offset = res
+    _, vjp = jax.vjp(
+        lambda q_, k_, v_: ref.flash_attention(
+            q_, k_, v_, causal=causal, window=window, q_offset=q_offset),
+        q, k, v,
     )
+    return (*vjp(g), None)
+
+
+_flash_pallas.defvjp(_flash_fwd, _flash_bwd)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *,
@@ -115,7 +152,7 @@ def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
 
 def ssd_chunk_scan(x, dt, a, b, c, *, chunk=128, d_skip=None,
                    init_state=None, impl: Optional[str] = None):
-    mode = _impl(impl)
+    mode = _impl(impl, tpu_kernel=False)
     if mode == "ref":
         return ref.ssd_chunk_scan(
             x, dt, a, b, c, chunk=chunk, d_skip=d_skip,

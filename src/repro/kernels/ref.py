@@ -149,10 +149,27 @@ def decode_attention(
 # ---------------------------------------------------------------------------
 # Paged decode attention (block tables)
 # ---------------------------------------------------------------------------
+def to_pages(kv: jax.Array, block: int) -> jax.Array:
+    """Token-major KV ``(..., n*block, Hkv, D)`` -> pool pages
+    ``(..., n, Hkv, block, D)``.  Pools are head-major so one kv head's
+    slice of a page is a contiguous ``(block, D)`` tile (the Pallas
+    kernel's block; see :mod:`repro.kernels.paged_attention`)."""
+    *lead, S, Hkv, D = kv.shape
+    kv = kv.reshape(*lead, S // block, block, Hkv, D)
+    return jnp.swapaxes(kv, -3, -2)
+
+
+def from_pages(pages: jax.Array) -> jax.Array:
+    """Inverse of :func:`to_pages`: ``(..., n, Hkv, block, D)`` ->
+    ``(..., n*block, Hkv, D)``."""
+    *lead, n, Hkv, block, D = pages.shape
+    return jnp.swapaxes(pages, -3, -2).reshape(*lead, n * block, Hkv, D)
+
+
 def paged_attention(
     q: jax.Array,            # (B, H, D)
-    k_pool: jax.Array,       # (B, N_blocks, block, Hkv, D) — per-seq pool
-    v_pool: jax.Array,       # (B, N_blocks, block, Hkv, D)
+    k_pool: jax.Array,       # (B, N_blocks, Hkv, block, D) — per-seq pool
+    v_pool: jax.Array,       # (B, N_blocks, Hkv, block, D)
     block_table: jax.Array,  # (B, max_blocks) int32 — local page ids
     lengths: jax.Array,      # (B,) int32
     *,
@@ -178,23 +195,17 @@ def paged_attention(
     """
     if n_kv is not None and n_kv < block_table.shape[1]:
         block_table = block_table[:, :n_kv]
-    B, H, D = q.shape
-    block = k_pool.shape[2]
-    Hkv = k_pool.shape[3]
-    max_blocks = block_table.shape[1]
+    B = q.shape[0]
     if global_pages:
-        n_pool = k_pool.shape[1]
-        kfl = k_pool.reshape(B * n_pool, block, Hkv, D)
-        vfl = v_pool.reshape(B * n_pool, block, Hkv, D)
-        k = jnp.take(kfl, block_table, axis=0)  # (B, MB, block, Hkv, D)
-        v = jnp.take(vfl, block_table, axis=0)
+        flat = (B * k_pool.shape[1],) + k_pool.shape[2:]
+        k = jnp.take(k_pool.reshape(flat), block_table, axis=0)
+        v = jnp.take(v_pool.reshape(flat), block_table, axis=0)
     else:
         idx = block_table[:, :, None, None, None]
         k = jnp.take_along_axis(k_pool, idx, axis=1)
         v = jnp.take_along_axis(v_pool, idx, axis=1)
-    k = k.reshape(B, max_blocks * block, Hkv, D)
-    v = v.reshape(B, max_blocks * block, Hkv, D)
-    return decode_attention(q, k, v, lengths)
+    # (B, MB, Hkv, block, D) -> (B, MB*block, Hkv, D)
+    return decode_attention(q, from_pages(k), from_pages(v), lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -316,7 +327,7 @@ def ssd_decode_step(
 # Block gather (pool compaction / defrag)
 # ---------------------------------------------------------------------------
 def block_gather(
-    pool: jax.Array,     # (N_blocks, block, Hkv, D)
+    pool: jax.Array,     # (N_blocks, Hkv, block, D)
     indices: jax.Array,  # (M,) int32 — source block ids
 ) -> jax.Array:
     """Gather M pages out of the pool (reclaimer compaction hot path)."""

@@ -8,7 +8,7 @@ production meshes.
 Attention supports GQA (+ optional QKV bias, sliding window) and three KV
 cache layouts:
   * contiguous — (B, S_max, Hkv, D), classic serving cache
-  * paged      — (N_blocks, block, Hkv, D) pool + (B, max_blocks) block
+  * paged      — (N_blocks, Hkv, block, D) pool + (B, max_blocks) block
                  tables; pages are recycled through the stamped BlockPool
                  (the paper's technique at the serving layer)
   * rolling    — (B, window, Hkv, D) ring buffer for sliding-window models
@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..kernels import ops
+from ..kernels.ref import from_pages, to_pages
 from .param import ParamSpec
 
 # ---------------------------------------------------------------------------
@@ -83,15 +84,20 @@ def attention_specs(
     M = cfg.d_model
     lead = (layers,) if layers else ()
     la = ("layers",) if layers else ()
+    # fan-in scaling over the contracted dims: d_model into q/k/v, heads x
+    # head_dim into wo.  (init="scaled" reads shape[-2], a head axis here:
+    # the random scores then saturate every softmax and a random deep
+    # model turns bf16 rounding into different tokens.)
+    w_in, w_out = M ** -0.5, (H * D) ** -0.5
     specs = {
         "wq": ParamSpec(lead + (M, H, D), la + ("embed", "heads", None),
-                        init="scaled"),
+                        scale=w_in),
         "wk": ParamSpec(lead + (M, Hkv, D), la + ("embed", "kv_heads", None),
-                        init="scaled"),
+                        scale=w_in),
         "wv": ParamSpec(lead + (M, Hkv, D), la + ("embed", "kv_heads", None),
-                        init="scaled"),
+                        scale=w_in),
         "wo": ParamSpec(lead + (H, D, M), la + ("heads", None, "embed"),
-                        init="scaled"),
+                        scale=w_out),
     }
     if cfg.qkv_bias:
         specs["bq"] = ParamSpec(lead + (H, D), la + ("heads", None),
@@ -219,38 +225,31 @@ def attention_decode(
             )
             out = jnp.einsum("bhd,hdm->bm", out, p["wo"].astype(dt))
             return out[:, None], dict(cache, k_pool=k_pool, v_pool=v_pool)
-        block = cache["k_pool"].shape[2]
+        block = cache["k_pool"].shape[3]
         barange = jnp.arange(B)
+        offs = lengths % block
         if global_pages:
             # ---- paged cache, slot-flattened global ids (CoW forks) ----
             n_pool = cache["k_pool"].shape[1]
-            Hkv, D = cache["k_pool"].shape[3], cache["k_pool"].shape[4]
             page_g = block_table[barange, lengths // block]  # (B,) global
-            offs = lengths % block
-            kfl = cache["k_pool"].reshape(B * n_pool, block, Hkv, D)
-            vfl = cache["v_pool"].reshape(B * n_pool, block, Hkv, D)
+            flat = (B * n_pool,) + cache["k_pool"].shape[2:]
             # inactive slots' zero rows all land on global page 0 (slot
             # 0's scratch page) — never read, same contract as the local
             # path's per-slot scratch page
-            kfl = kfl.at[page_g, offs].set(k1)
-            vfl = vfl.at[page_g, offs].set(v1)
-            k_pool = kfl.reshape(cache["k_pool"].shape)
-            v_pool = vfl.reshape(cache["v_pool"].shape)
-            out = ops.paged_attention(
-                q1, k_pool, v_pool, block_table, lengths + 1, n_kv=n_kv,
-                global_pages=True,
-            )
-            new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool)
+            k_pool = cache["k_pool"].reshape(flat).at[page_g, :, offs].set(
+                k1).reshape(cache["k_pool"].shape)
+            v_pool = cache["v_pool"].reshape(flat).at[page_g, :, offs].set(
+                v1).reshape(cache["v_pool"].shape)
         else:
             # ---- paged cache (per-sequence-local pools) ----
             page = block_table[barange, lengths // block]  # (B,) local id
-            slot = lengths % block
-            k_pool = cache["k_pool"].at[barange, page, slot].set(k1)
-            v_pool = cache["v_pool"].at[barange, page, slot].set(v1)
-            out = ops.paged_attention(
-                q1, k_pool, v_pool, block_table, lengths + 1, n_kv=n_kv
-            )
-            new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool)
+            k_pool = cache["k_pool"].at[barange, page, :, offs].set(k1)
+            v_pool = cache["v_pool"].at[barange, page, :, offs].set(v1)
+        out = ops.paged_attention(
+            q1, k_pool, v_pool, block_table, lengths + 1, n_kv=n_kv,
+            global_pages=global_pages,
+        )
+        new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool)
     elif cfg.sliding_window and cache["k"].shape[1] == cfg.sliding_window:
         # ---- rolling (sliding-window) cache ----
         W = cfg.sliding_window
@@ -310,37 +309,26 @@ def attention_chunk(
     into the slot-flattened pool (``slot`` is then only the scratch-row
     owner); writes and the row gather address the flat pool directly.
     """
-    B, C, M = x.shape
     dt = x.dtype
     q, k, v = _project_qkv(p, x, cfg)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
-    block = cache["k_pool"].shape[2]
-    Hkv, D = cache["k_pool"].shape[3], cache["k_pool"].shape[4]
-    nc = C // block
+    block = cache["k_pool"].shape[3]
+    kc = to_pages(k[0], block).astype(cache["k_pool"].dtype)  # (nc,Hkv,bl,D)
+    vc = to_pages(v[0], block).astype(cache["v_pool"].dtype)
     if global_pages:
-        n_slots, n_pool = cache["k_pool"].shape[0], cache["k_pool"].shape[1]
-        kfl = cache["k_pool"].reshape(n_slots * n_pool, block, Hkv, D)
-        vfl = cache["v_pool"].reshape(n_slots * n_pool, block, Hkv, D)
-        kfl = kfl.at[pages].set(
-            k[0].reshape(nc, block, Hkv, D).astype(kfl.dtype)
-        )
-        vfl = vfl.at[pages].set(
-            v[0].reshape(nc, block, Hkv, D).astype(vfl.dtype)
-        )
-        gk = kfl[row[:n_kv]].reshape(1, n_kv * block, Hkv, D)
-        gv = vfl[row[:n_kv]].reshape(1, n_kv * block, Hkv, D)
+        flat = (-1,) + cache["k_pool"].shape[2:]
+        kfl = cache["k_pool"].reshape(flat).at[pages].set(kc)
+        vfl = cache["v_pool"].reshape(flat).at[pages].set(vc)
+        gk = from_pages(kfl[row[:n_kv]])[None]  # (1, n_kv*block, Hkv, D)
+        gv = from_pages(vfl[row[:n_kv]])[None]
         kp = kfl.reshape(cache["k_pool"].shape)
         vp = vfl.reshape(cache["v_pool"].shape)
     else:
-        kp = cache["k_pool"].at[slot, pages].set(
-            k[0].reshape(nc, block, Hkv, D).astype(cache["k_pool"].dtype)
-        )
-        vp = cache["v_pool"].at[slot, pages].set(
-            v[0].reshape(nc, block, Hkv, D).astype(cache["v_pool"].dtype)
-        )
-        gk = kp[slot][row[:n_kv]].reshape(1, n_kv * block, Hkv, D)
-        gv = vp[slot][row[:n_kv]].reshape(1, n_kv * block, Hkv, D)
+        kp = cache["k_pool"].at[slot, pages].set(kc)
+        vp = cache["v_pool"].at[slot, pages].set(vc)
+        gk = from_pages(kp[slot][row[:n_kv]])[None]
+        gv = from_pages(vp[slot][row[:n_kv]])[None]
     out = ops.flash_attention(q, gk, gv, causal=True, q_offset=positions[0])
     out = jnp.einsum("bshd,hdm->bsm", out, p["wo"].astype(dt))
     return out, dict(cache, k_pool=kp, v_pool=vp)

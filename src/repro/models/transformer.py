@@ -167,18 +167,19 @@ def _attn_cache_specs(cfg, n_layers, batch, max_seq, layout, dtype,
     #     to a TP_WIDTH multiple so the dim divides) and use the
     #     distributed flash-decode (kernels/distributed.py) to avoid pool
     #     all-gathers.
+    # Pages are head-major (Hkv, block, D): see kernels.ref.to_pages.
     mb = -(-max_seq // BLOCK_SIZE) + 1 + pool_slack
     if pool_slack == 0 and paged_blocks_sharded_cfg(cfg):
         mb = -(-mb // TP_WIDTH) * TP_WIDTH
     blocks_ax = "blocks" if paged_blocks_sharded_cfg(cfg) else None
     return {
         "k_pool": ParamSpec(
-            (n_layers, batch, mb, BLOCK_SIZE, Hkv, D),
-            ("layers", "batch", blocks_ax, None, "kv_heads", None),
+            (n_layers, batch, mb, Hkv, BLOCK_SIZE, D),
+            ("layers", "batch", blocks_ax, "kv_heads", None, None),
             dtype=dtype, init="zeros"),
         "v_pool": ParamSpec(
-            (n_layers, batch, mb, BLOCK_SIZE, Hkv, D),
-            ("layers", "batch", blocks_ax, None, "kv_heads", None),
+            (n_layers, batch, mb, Hkv, BLOCK_SIZE, D),
+            ("layers", "batch", blocks_ax, "kv_heads", None, None),
             dtype=dtype, init="zeros"),
     }
 

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.ref import to_pages
+
 
 def sample_tokens(logits, u, temperature: float, top_p: float):
     """Temperature/top-p sampling via sorted inverse CDF (pure jnp).
@@ -112,6 +114,7 @@ class DeviceState:
         global_pages: bool = False,
         speculate_k: int = 0,
         draft_layers: int = 0,
+        device: Optional[jax.Device] = None,
     ) -> None:
         self.model = model
         self.params = params
@@ -146,37 +149,43 @@ class DeviceState:
         self.chunk_shapes: set = set()
 
         B = max_slots
-        self.tokens = jnp.zeros((B, 1), jnp.int32)
-        self.lengths = jnp.zeros((B,), jnp.int32)
-        self.table = jnp.zeros((B, mb), jnp.int32)
-        self.mask = jnp.zeros((B,), jnp.int32)
-        self.pages = jnp.zeros((B,), jnp.int32)
-        self.first_buf = jnp.zeros((B,), jnp.int32)
-        # per-slot sample keys (installed at admit): sampling is a pure
-        # function of (key, position) — see counter_uniform — so a
-        # request's stream is replica-independent.  The legacy engine rng
-        # chain is gone; `seed` survives only as the engine-level default
-        # key derivation salt (see ServingEngine.submit).
-        self.seeds = jnp.zeros((B,), jnp.int32)
+        # step operands live on the engine's device, beside the committed
+        # params and cache (None: the default device)
+        with jax.default_device(device):
+            self.tokens = jnp.zeros((B, 1), jnp.int32)
+            self.lengths = jnp.zeros((B,), jnp.int32)
+            self.table = jnp.zeros((B, mb), jnp.int32)
+            self.mask = jnp.zeros((B,), jnp.int32)
+            self.pages = jnp.zeros((B,), jnp.int32)
+            self.first_buf = jnp.zeros((B,), jnp.int32)
+            # per-slot sample keys (installed at admit): sampling is a
+            # pure function of (key, position) — see counter_uniform — so
+            # a request's stream is replica-independent.  The legacy
+            # engine rng chain is gone; `seed` survives only as the
+            # engine-level default key derivation salt (see
+            # ServingEngine.submit).
+            self.seeds = jnp.zeros((B,), jnp.int32)
+            # shared all-zeros operands for the steady state (no events
+            # pending) — device-resident so the common dispatch passes
+            # already-committed buffers instead of re-uploading numpy
+            # zeros; event paths build fresh numpy arrays (same avals,
+            # same compile)
+            self._zeros = jnp.zeros((B,), jnp.int32)
+            self._zeros_row = jnp.zeros((B, mb), jnp.int32)
+            # chunk-lane dummies (unused by the has_chunk=False variant,
+            # but the jit signature is shared, so the avals must stay
+            # fixed)
+            self._zero = jnp.int32(0)
+            self._ck_zeros_toks = jnp.zeros(
+                (1, max(self.chunk_tokens, 1)), jnp.int32)
+            self._ck_zeros_row = jnp.zeros((mb,), jnp.int32)
+            self._ck_zeros_pages = jnp.zeros(
+                (max(self.chunk_tokens // block, 1),), jnp.int32)
 
         # staged host events, applied by the next fused dispatch
         self._pending_resets: List[int] = []
         self._pending_admits: List[Tuple] = []
         self._pending_chunk: Optional[Tuple] = None
-        # shared all-zeros operands for the steady state (no events
-        # pending) — device-resident so the common dispatch passes
-        # already-committed buffers instead of re-uploading numpy zeros;
-        # event paths build fresh numpy arrays (same avals, same compile)
-        self._zeros = jnp.zeros((B,), jnp.int32)
-        self._zeros_row = jnp.zeros((B, mb), jnp.int32)
-        # chunk-lane dummies (unused by the has_chunk=False variant, but
-        # the jit signature is shared, so the avals must stay fixed)
-        self._zero = jnp.int32(0)
-        self._ck_zeros_toks = jnp.zeros((1, max(self.chunk_tokens, 1)),
-                                        jnp.int32)
-        self._ck_zeros_row = jnp.zeros((mb,), jnp.int32)
-        self._ck_zeros_pages = jnp.zeros(
-            (max(self.chunk_tokens // block, 1),), jnp.int32)
         self.stage_ns = 0  # host time spent building step operands
 
         # dispatch accounting (decode plane vs admission plane).  Any
@@ -388,13 +397,10 @@ class DeviceState:
             first = sample_tokens(logits, u, self.temperature, self.top_p)
         else:
             first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        k, v = kv["k"], kv["v"]
-        L = k.shape[0]
-        S = tokens.shape[1]
-        nb = S // self.block  # full bucket; spare blocks land on page 0
+        # the whole bucket scatters; spare blocks land on scratch page 0
         kp = cache["layers"]["k_pool"]
-        kr = k[:, :, :S].reshape(L, nb, self.block, k.shape[3], k.shape[4])
-        vr = v[:, :, :S].reshape(L, nb, self.block, k.shape[3], k.shape[4])
+        kr = to_pages(kv["k"][:, 0], self.block)  # (L, nb, Hkv, block, D)
+        vr = to_pages(kv["v"][:, 0], self.block)
         kp = kp.at[:, slot, pages].set(kr.astype(kp.dtype))
         vp = cache["layers"]["v_pool"].at[:, slot, pages].set(
             vr.astype(kp.dtype)
@@ -510,7 +516,7 @@ class DeviceState:
         return b
 
     def read_pages(self, slot: int, pages) -> Tuple[np.ndarray, np.ndarray]:
-        """Pull one slot's pages to host: (L, n, block, Hkv, D) k/v pair.
+        """Pull one slot's pages to host: (L, n, Hkv, block, D) k/v pair.
         Synchronous by design — migration is not the hot path, and the
         caller holds a cluster hold so the pages cannot be reclaimed."""
         n = len(pages)
@@ -564,6 +570,37 @@ class DeviceState:
                    construction, see module docstring).
         """
         t0 = time.perf_counter_ns()
+        has_chunk = self._pending_chunk is not None
+        operands = self._operands(tf, grow)
+        self.stage_ns += time.perf_counter_ns() - t0
+
+        out = self._step(*operands, n_kv, has_chunk)
+        spec = None
+        if self.speculate_k > 0:
+            (self.tokens, self.cache, self.lengths, self.table, self.mask,
+             self.pages, self.first_buf, self.seeds, chunk_first, v,
+             counts) = out
+            spec = (v, counts)
+        else:
+            (self.tokens, self.cache, self.lengths, self.table, self.mask,
+             self.pages, self.first_buf, self.seeds, chunk_first) = out
+        self._pending_resets.clear()
+        self._pending_admits.clear()
+        self._pending_chunk = None
+        self.decode_dispatches += 1
+        return self.tokens, chunk_first, spec
+
+    def compiled_step_text(self, n_kv: int, has_chunk: bool) -> str:
+        """Compiled program text of one fused-step variant, lowered with
+        the current operand avals — what the device runs (e.g. to confirm
+        that a Pallas kernel is in it).  Compiles; runs nothing."""
+        lowered = self._step.lower(*self._operands({}, {}), n_kv, has_chunk)
+        return lowered.compile().as_text()
+
+    def _operands(self, tf: Dict[int, int], grow: Dict[int, int]) -> tuple:
+        """The fused step's positional operands for the staged events:
+        fresh numpy arrays where an event is staged, the device-resident
+        zeros otherwise (same avals either way)."""
         B, mb = self.max_slots, self.mb
         zeros = self._zeros
         reset_m = zeros
@@ -605,13 +642,12 @@ class DeviceState:
             cand = np.zeros((B,), np.int32)
             for slot, page in grow.items():
                 cand[slot] = page
-        has_chunk = self._pending_chunk is not None
         ck_tokens = self._ck_zeros_toks
         ck_slot = ck_start = ck_last = ck_last_index = self._zero
         ck_seed = self._zero
         ck_row = self._ck_zeros_row
         ck_pages = self._ck_zeros_pages
-        if has_chunk:
+        if self._pending_chunk is not None:
             (c_slot, c_toks, c_start, c_row, c_pages, c_is_last,
              c_last_index, c_seed) = self._pending_chunk
             ck_tokens = np.asarray(c_toks, np.int32)[None]
@@ -622,27 +658,10 @@ class DeviceState:
             ck_last = np.int32(1 if c_is_last else 0)
             ck_last_index = np.int32(c_last_index)
             ck_seed = np.int32(c_seed)
-        self.stage_ns += time.perf_counter_ns() - t0
-
-        out = self._step(
-            self.params, self.cache, self.tokens, self.lengths, self.table,
-            self.mask, self.pages, self.first_buf, self.seeds, reset_m,
-            admit_m, admit_len, admit_row, admit_pages, admit_tok,
-            admit_from_buf, admit_set_tok, admit_seed, tf_m, tf_vals, cand,
-            ck_tokens, ck_slot, ck_start, ck_row, ck_pages, ck_last,
-            ck_last_index, ck_seed, n_kv, has_chunk,
-        )
-        spec = None
-        if self.speculate_k > 0:
-            (self.tokens, self.cache, self.lengths, self.table, self.mask,
-             self.pages, self.first_buf, self.seeds, chunk_first, v,
-             counts) = out
-            spec = (v, counts)
-        else:
-            (self.tokens, self.cache, self.lengths, self.table, self.mask,
-             self.pages, self.first_buf, self.seeds, chunk_first) = out
-        self._pending_resets.clear()
-        self._pending_admits.clear()
-        self._pending_chunk = None
-        self.decode_dispatches += 1
-        return self.tokens, chunk_first, spec
+        return (self.params, self.cache, self.tokens, self.lengths,
+                self.table, self.mask, self.pages, self.first_buf,
+                self.seeds, reset_m, admit_m, admit_len, admit_row,
+                admit_pages, admit_tok, admit_from_buf, admit_set_tok,
+                admit_seed, tf_m, tf_vals, cand, ck_tokens, ck_slot,
+                ck_start, ck_row, ck_pages, ck_last, ck_last_index,
+                ck_seed)

@@ -76,7 +76,11 @@ class ServingEngine:
         draft_layers: Optional[int] = None,
         registry: Optional[Registry] = None,
         spans: Optional[SpanRecorder] = None,
+        device: Optional[jax.Device] = None,
     ) -> None:
+        """``device`` places this engine: its params, KV pool and every
+        step operand are committed there, so the fused step runs on that
+        device (one replica per chip).  ``None`` keeps JAX's default."""
         cfg = model.cfg
         assert cache_layout(cfg) == "paged", (
             "the engine drives paged-layout archs (dense/MoE w/o SWA)"
@@ -138,6 +142,9 @@ class ServingEngine:
             # passes it in); standalone engines build their own
             params = model.init_params(seed)
         cache = model.init_cache(shape, pool_slack=extra_pages_per_slot)
+        if device is not None:
+            params, cache = jax.device_put((params, cache), device)
+        self.device = device
 
         # page 0 of each slot is the scratch page: inactive slots keep a
         # zeroed block-table row, so their (discarded) decode writes land
@@ -167,7 +174,7 @@ class ServingEngine:
             block=self.block, temperature=temperature, top_p=top_p,
             seed=sample_seed, chunk_tokens=chunk_tokens,
             global_pages=True, speculate_k=speculate_k,
-            draft_layers=self.draft_layers,
+            draft_layers=self.draft_layers, device=device,
         )
 
         # page-ref cache: rebuilt only when the active page set changes

@@ -128,6 +128,7 @@ def _load_prefill_into_cache(m, cache, pcache, S):
     """Scatter prefill KV/state into the decode cache layout."""
     import jax.numpy as jnp
 
+    from repro.kernels.ref import to_pages
     from repro.models.transformer import BLOCK_SIZE, cache_layout
 
     cfg = m.cfg
@@ -135,11 +136,11 @@ def _load_prefill_into_cache(m, cache, pcache, S):
     cache = jax.tree.map(lambda x: x, cache)  # shallow copy
 
     def paged_fill(pool, kv):  # kv: (L,B,S,Hkv,D)
-        L, B, S_, Hkv, D = kv.shape
+        S_ = kv.shape[2]
         nb = -(-S_ // BLOCK_SIZE)
         pad = nb * BLOCK_SIZE - S_
         kvp = jnp.pad(kv, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-        kvp = kvp.reshape(L, B, nb, BLOCK_SIZE, Hkv, D)
+        kvp = to_pages(kvp, BLOCK_SIZE)  # (L, B, nb, Hkv, block, D)
         return pool.at[:, :, :nb].set(kvp.astype(pool.dtype))
 
     if layout == "ssm":

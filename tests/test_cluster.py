@@ -134,6 +134,93 @@ def test_group_matches_single_engine(model):
     assert {r for _, r in group.route_trace} == {0, 1}
 
 
+PLACEMENT_SCRIPT = r'''
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.cluster import ReplicaGroup
+from repro.configs import ARCHS, smoke_config
+from repro.models import Model
+from repro.serving import ServingEngine
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+model = Model(smoke_config(ARCHS["qwen2-0.5b"]))
+rs = np.random.RandomState(5)
+prompts = [list(rs.randint(1, 500, n)) for n in (40, 150, 9, 200, 64, 130)]
+
+eng = ServingEngine(model, max_slots=2, max_seq=512)
+ref = [eng.submit(p, max_new_tokens=5) for p in prompts]
+eng.run_until_done()
+
+group = ReplicaGroup(model, 4, max_slots=2, max_seq=512,
+                     router="round-robin", devices=devs)
+reqs = [group.submit(p, max_new_tokens=5) for p in prompts]
+group.run_until_done()
+group.drain()
+for i, e in enumerate(group.engines):
+    leaves = jax.tree_util.tree_leaves(
+        (e.dev.params, e.dev.cache, e.dev.tokens, e.dev.table))
+    assert all(x.devices() == {devs[i]} for x in leaves), i
+    assert all(x.committed for x in leaves[:-2]), i  # params + cache
+assert {r for _, r in group.route_trace} == {0, 1, 2, 3}
+assert [r.generated for r in reqs] == [r.generated for r in ref]
+print("PLACEMENT_OK")
+
+# host-side page moves between replicas on different devices
+from repro.cluster.migration import migrate_prefix
+pair = ReplicaGroup(model, 2, max_slots=2, max_seq=512,
+                    router="prefix-affinity", prefix_cache_entries=8,
+                    extra_pages_per_slot=6, devices=devs[2:])
+prompt = prompts[3]  # 200 tokens: one full cached page
+r1 = pair.submit(prompt, max_new_tokens=5)
+pair.run_until_done()
+src = pair.route_trace[0][1]
+report = migrate_prefix(pair, prompt, src, 1 - src)
+assert report["imported"] == 1, report
+cache = pair.engines[1 - src].dev.cache
+assert all(x.devices() == {devs[2 + 1 - src]}
+           for x in jax.tree_util.tree_leaves(cache))
+r2 = pair.submit(prompt, max_new_tokens=5)
+pair.run_until_done()
+pair.drain()
+assert pair.route_trace[-1][1] == 1 - src
+assert r2.generated == r1.generated == ref[3].generated
+print("MIGRATION_OK")
+
+import sys
+sys.path.insert(0, ROOT)
+import chip_smoke  # the script's four-chip phase, rehearsed on host devices
+chip_smoke.four_chips(model, 0, devs, slots=2, max_seq=1024)
+print("CHIP_SMOKE_FOUR_OK")
+'''
+
+
+def test_replicas_on_their_own_devices():
+    """devices=: replica i commits its params and cache to device i (four
+    forced host devices, in a subprocess because the device count is
+    fixed at JAX start-up); tokens equal a single engine's; a prefix
+    migrates between replicas on different devices and replays the same
+    tokens.  Then runs chip_smoke.py's four-chip phase on the same
+    devices."""
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).parents[1]
+    r = subprocess.run(
+        [sys.executable, "-c",
+         PLACEMENT_SCRIPT.replace("ROOT", repr(str(root)))],
+        capture_output=True, text=True, timeout=480,
+        env={"PYTHONPATH": str(root / "src"),
+             "PATH": "/usr/bin:/bin:/usr/local/bin",
+             "JAX_PLATFORMS": "cpu"},
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    for marker in ("PLACEMENT_OK", "MIGRATION_OK", "CHIP_SMOKE_FOUR_OK"):
+        assert marker in r.stdout, (marker, r.stdout, r.stderr[-1500:])
+
+
 def test_group_checkpoint_hold_defers_then_recovers(model):
     """A checkpoint hold spanning finishes pins their retired pages on
     every replica; teardown (`drain`) releases leaked holds FIRST, so a

@@ -24,8 +24,8 @@ rs = np.random.RandomState(0)
 # ---------------- paged flash-decode ----------------
 B, MB, blk, Hkv, H, D, MBl = 4, 8, 16, 2, 4, 32, 6
 q  = jnp.asarray(rs.randn(B, H, D), jnp.float32) * 0.3
-kp = jnp.asarray(rs.randn(B, MB, blk, Hkv, D), jnp.float32) * 0.3
-vp = jnp.asarray(rs.randn(B, MB, blk, Hkv, D), jnp.float32) * 0.3
+kp = jnp.asarray(rs.randn(B, MB, Hkv, blk, D), jnp.float32) * 0.3
+vp = jnp.asarray(rs.randn(B, MB, Hkv, blk, D), jnp.float32) * 0.3
 k1 = jnp.asarray(rs.randn(B, Hkv, D), jnp.float32) * 0.3
 v1 = jnp.asarray(rs.randn(B, Hkv, D), jnp.float32) * 0.3
 table = jnp.asarray(
@@ -34,8 +34,8 @@ lengths = jnp.asarray(rs.randint(1, MBl * blk - 1, (B,)), jnp.int32)
 
 bar = jnp.arange(B)
 page, slot = table[bar, lengths // blk], lengths % blk
-kp_ref = kp.at[bar, page, slot].set(k1)
-vp_ref = vp.at[bar, page, slot].set(v1)
+kp_ref = kp.at[bar, page, :, slot].set(k1)
+vp_ref = vp.at[bar, page, :, slot].set(v1)
 want = ref.paged_attention(q, kp_ref, vp_ref, table, lengths + 1)
 with mesh:
     got, kp2, vp2 = jax.jit(

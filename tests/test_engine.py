@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCHS, ShapeConfig, smoke_config
+from repro.kernels.ref import to_pages
 from repro.memory import POLICIES
 from repro.models import Model
 from repro.models.transformer import BLOCK_SIZE
@@ -42,13 +43,12 @@ def reference_generate(model, prompt, max_new):
     pad = nb * BLOCK_SIZE - S
     k = jnp.pad(kv["k"], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
     v = jnp.pad(kv["v"], ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-    L = k.shape[0]
-    kr = k.reshape(L, 1, nb, BLOCK_SIZE, k.shape[3], k.shape[4])
+    kr = to_pages(k, BLOCK_SIZE)  # (L, 1, nb, Hkv, block, D)
     cache["layers"]["k_pool"] = (
         cache["layers"]["k_pool"].at[:, :, :nb].set(
             kr.astype(cache["layers"]["k_pool"].dtype))
     )
-    vr = v.reshape(L, 1, nb, BLOCK_SIZE, v.shape[3], v.shape[4])
+    vr = to_pages(v, BLOCK_SIZE)
     cache["layers"]["v_pool"] = (
         cache["layers"]["v_pool"].at[:, :, :nb].set(
             vr.astype(cache["layers"]["v_pool"].dtype))
@@ -266,3 +266,32 @@ def test_ledger_blocks_reuse_while_inflight(model):
     eng.drain()
     assert saw_deferred, "expected retired-but-not-reclaimed pages"
     assert eng.pool.unreclaimed() == 0
+
+
+def _chip_smoke():
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    import chip_smoke
+
+    return chip_smoke
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """The chip check never falls back to the CPU: no result, non-zero
+    exit."""
+    with pytest.raises(SystemExit) as exc:
+        _chip_smoke().main([])
+    assert exc.value.code not in (0, None)
+
+
+def test_chip_smoke_phases_rehearse_on_cpu(model, monkeypatch):
+    """chip_smoke's one-chip phases at smoke size with the Pallas bodies
+    interpreted: kernel selection by name, Pallas-vs-ref logits, and
+    stamp-it == epoch tokens."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "interpret")
+    _chip_smoke().one_chip(model, 0, expected_impl="interpret",
+                           check_step_text=False, max_seq=1024)

@@ -117,3 +117,27 @@ def test_trainer_straggler_flagging():
     tr = Trainer(model, SHAPE, mesh, step_deadline_s=1e-9, seed=0)
     out = tr.run(2)
     assert out["stragglers"] == [0, 1]  # every step exceeds a 1ns deadline
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """Entry points keep JAX's compile cache in $JAX_COMPILATION_CACHE_DIR
+    when it is set and in <checkout>/.jax_cache otherwise."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.compile_cache import enable_compile_cache
+
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

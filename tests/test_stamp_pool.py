@@ -2,9 +2,11 @@
 
 import random
 import threading
+import time
 
 import pytest
 
+from repro.core.atomics import MarkedValue
 from repro.core.stamp_pool import (
     NOT_IN_LIST,
     PENDING_PUSH,
@@ -120,6 +122,42 @@ def test_reentry_interleaved():
     assert pool.lowest_stamp() <= pool.head.stamp.load()
 
 
+def test_unlink_helper_rejects_a_recycled_successor():
+    """A remover that stepped from ``head`` to ``x`` and saw ``x``
+    marked must not unlink ``x`` after ``x`` left and re-entered the
+    pool: ``head.prev`` names ``x`` again, but with a new tag.  Unlinking
+    on the stale view would point ``head`` at a block that left the pool
+    (and the next push of that block would link it to itself)."""
+    pool = StampPool()
+    x, z = Block("x"), Block("z")
+    pool.push(z)
+    x_stamp = pool.push(x)                 # head -> x -> z -> tail
+    seen = pool.head.prev.load()           # the remover's step to x
+    stale = x.prev.load().with_mark()      # ... and x.prev, marked
+    pool.remove(x)
+    pool.remove(z)
+    pool.push(x)                           # x is back: head -> x -> tail
+    pool._remove_or_skip_marked_block(seen, MarkedValue(pool.head), stale,
+                                      x_stamp)
+    assert pool.head.prev.load().obj is x
+    pool.remove(x)
+    pool.check_quiescent_invariants()
+    assert pool.prev_chain() == [pool.head, pool.tail]
+
+
+#: a stress run takes seconds; a thread still inside push/remove after
+#: this long is live-locked, which fails the test instead of hanging it
+JOIN_TIMEOUT_S = 60.0
+
+
+def _join_all(threads):
+    deadline = time.monotonic() + JOIN_TIMEOUT_S
+    for t in threads:
+        t.join(max(deadline - time.monotonic(), 0.0))
+    stuck = [t.name for t in threads if t.is_alive()]
+    assert not stuck, f"live-locked in the stamp pool: {stuck}"
+
+
 @pytest.mark.parametrize("n_threads,iters", [(4, 400), (8, 250)])
 def test_stress_concurrent_push_remove(n_threads, iters):
     """Concurrent enter/leave cycles; validate the tail-stamp safety
@@ -150,12 +188,12 @@ def test_stress_concurrent_push_remove(n_threads, iters):
             errors.append(traceback.format_exc())
 
     threads = [
-        threading.Thread(target=worker, args=(i,)) for i in range(n_threads)
+        threading.Thread(target=worker, args=(i,), daemon=True)
+        for i in range(n_threads)
     ]
     for t in threads:
         t.start()
-    for t in threads:
-        t.join()
+    _join_all(threads)
     assert not errors, errors[:3]
     pool.check_quiescent_invariants()
     # pool must be empty again
@@ -186,15 +224,13 @@ def test_stress_staggered_lifetimes():
             errors.append(traceback.format_exc())
 
     threads = [
-        threading.Thread(target=worker, args=(i,)) for i in range(n_threads)
+        threading.Thread(target=worker, args=(i,), daemon=True)
+        for i in range(n_threads)
     ]
     for t in threads:
         t.start()
-    import time
-
     time.sleep(1.5)
     stop.set()
-    for t in threads:
-        t.join()
+    _join_all(threads)
     assert not errors, errors[:3]
     pool.check_quiescent_invariants()
