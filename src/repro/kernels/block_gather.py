@@ -40,4 +40,5 @@ def block_gather_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((m, hkv, block, d), pool.dtype),
         interpret=interpret,
+        name="block_gather",
     )(indices, pool)

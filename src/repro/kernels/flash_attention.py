@@ -134,6 +134,7 @@ def flash_attention_pallas(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(jnp.asarray(q_offset, jnp.int32).reshape(1),
       q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
       v.transpose(0, 2, 1, 3))

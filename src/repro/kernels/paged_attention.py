@@ -78,8 +78,10 @@ def _paged_kernel(table_ref, lengths_ref, *refs, **kw):
 
 
 def _attend(kernel, scalars, q, k_op, v_op, kv_spec, *, Hkv, n_kv, block,
-            interpret):
-    """Shared pallas_call: grid (B, Hkv, n_kv) over GQA query groups."""
+            interpret, name):
+    """Shared pallas_call: grid (B, Hkv, n_kv) over GQA query groups.
+    ``name`` is the kernel's stable name: the compiled custom call and
+    its events in a profile carry it."""
     B, H, D = q.shape
     G = H // Hkv
     q_spec = pl.BlockSpec((None, None, G, D), lambda b, h, ik, *_: (b, h, 0, 0))
@@ -102,6 +104,7 @@ def _attend(kernel, scalars, q, k_op, v_op, kv_spec, *, Hkv, n_kv, block,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name=name,
     )(*scalars, q.reshape(B, Hkv, G, D), k_op, v_op)
     return out.reshape(B, H, D)
 
@@ -127,6 +130,7 @@ def decode_attention_pallas(
         _decode_kernel, (lengths,), q, k_cache.transpose(0, 2, 1, 3),
         v_cache.transpose(0, 2, 1, 3), kv_spec, Hkv=Hkv,
         n_kv=S // block_k, block=block_k, interpret=interpret,
+        name="decode_attention",
     )
 
 
@@ -176,5 +180,5 @@ def paged_attention_pallas(
     return _attend(
         _paged_kernel, (block_table, lengths), q, k_op, v_op, kv_spec,
         Hkv=Hkv, n_kv=block_table.shape[1], block=block,
-        interpret=interpret,
+        interpret=interpret, name="paged_attention",
     )

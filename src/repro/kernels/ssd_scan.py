@@ -135,5 +135,6 @@ def ssd_chunk_scan_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ssd_scan",
     )(x, dt, a, b, c, d)
     return y, state

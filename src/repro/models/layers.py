@@ -182,26 +182,33 @@ def attention_decode(
     B, S1, M = x.shape
     assert S1 == 1
     dt = x.dtype
-    q = jnp.einsum("bsm,mhd->bshd", x, p["wq"].astype(dt))
-    if "bq" in p:
-        q = q + p["bq"].astype(dt)
-    if use_rope and not cross:
-        q = apply_rope(q, lengths[:, None], cfg.rope_theta)
-    q1 = q[:, 0]  # (B, H, D)
+    # named scopes: attn.qkv (projections, RoPE), attn.kv_write (the
+    # cache update), attn.kernel (the attention itself)
+    scope = jax.named_scope
+    with scope("attn.qkv"):
+        q = jnp.einsum("bsm,mhd->bshd", x, p["wq"].astype(dt))
+        if "bq" in p:
+            q = q + p["bq"].astype(dt)
+        if use_rope and not cross:
+            q = apply_rope(q, lengths[:, None], cfg.rope_theta)
+        q1 = q[:, 0]  # (B, H, D)
 
     if cross:
-        out = ops.decode_attention(q1, cache["k"], cache["v"], cache["len"])
+        with scope("attn.kernel"):
+            out = ops.decode_attention(q1, cache["k"], cache["v"],
+                                       cache["len"])
         out = jnp.einsum("bhd,hdm->bm", out, p["wo"].astype(dt))
         return out[:, None], cache
 
-    k_new = jnp.einsum("bsm,mhd->bshd", x, p["wk"].astype(dt))
-    v_new = jnp.einsum("bsm,mhd->bshd", x, p["wv"].astype(dt))
-    if "bk" in p:
-        k_new = k_new + p["bk"].astype(dt)
-        v_new = v_new + p["bv"].astype(dt)
-    if use_rope:
-        k_new = apply_rope(k_new, lengths[:, None], cfg.rope_theta)
-    k1, v1 = k_new[:, 0], v_new[:, 0]  # (B, Hkv, D)
+    with scope("attn.qkv"):
+        k_new = jnp.einsum("bsm,mhd->bshd", x, p["wk"].astype(dt))
+        v_new = jnp.einsum("bsm,mhd->bshd", x, p["wv"].astype(dt))
+        if "bk" in p:
+            k_new = k_new + p["bk"].astype(dt)
+            v_new = v_new + p["bv"].astype(dt)
+        if use_rope:
+            k_new = apply_rope(k_new, lengths[:, None], cfg.rope_theta)
+        k1, v1 = k_new[:, 0], v_new[:, 0]  # (B, Hkv, D)
 
     if block_table is not None:
         dist = ops.dist_decode_config()
@@ -228,27 +235,29 @@ def attention_decode(
         block = cache["k_pool"].shape[3]
         barange = jnp.arange(B)
         offs = lengths % block
-        if global_pages:
-            # ---- paged cache, slot-flattened global ids (CoW forks) ----
-            n_pool = cache["k_pool"].shape[1]
-            page_g = block_table[barange, lengths // block]  # (B,) global
-            flat = (B * n_pool,) + cache["k_pool"].shape[2:]
-            # inactive slots' zero rows all land on global page 0 (slot
-            # 0's scratch page) — never read, same contract as the local
-            # path's per-slot scratch page
-            k_pool = cache["k_pool"].reshape(flat).at[page_g, :, offs].set(
-                k1).reshape(cache["k_pool"].shape)
-            v_pool = cache["v_pool"].reshape(flat).at[page_g, :, offs].set(
-                v1).reshape(cache["v_pool"].shape)
-        else:
-            # ---- paged cache (per-sequence-local pools) ----
-            page = block_table[barange, lengths // block]  # (B,) local id
-            k_pool = cache["k_pool"].at[barange, page, :, offs].set(k1)
-            v_pool = cache["v_pool"].at[barange, page, :, offs].set(v1)
-        out = ops.paged_attention(
-            q1, k_pool, v_pool, block_table, lengths + 1, n_kv=n_kv,
-            global_pages=global_pages,
-        )
+        with scope("attn.kv_write"):
+            if global_pages:
+                # ---- paged cache, slot-flattened global ids (CoW) ----
+                n_pool = cache["k_pool"].shape[1]
+                page_g = block_table[barange, lengths // block]  # global
+                flat = (B * n_pool,) + cache["k_pool"].shape[2:]
+                # inactive slots' zero rows all land on global page 0
+                # (slot 0's scratch page) — never read, same contract as
+                # the local path's per-slot scratch page
+                k_pool = cache["k_pool"].reshape(flat).at[
+                    page_g, :, offs].set(k1).reshape(cache["k_pool"].shape)
+                v_pool = cache["v_pool"].reshape(flat).at[
+                    page_g, :, offs].set(v1).reshape(cache["v_pool"].shape)
+            else:
+                # ---- paged cache (per-sequence-local pools) ----
+                page = block_table[barange, lengths // block]  # local id
+                k_pool = cache["k_pool"].at[barange, page, :, offs].set(k1)
+                v_pool = cache["v_pool"].at[barange, page, :, offs].set(v1)
+        with scope("attn.kernel"):
+            out = ops.paged_attention(
+                q1, k_pool, v_pool, block_table, lengths + 1, n_kv=n_kv,
+                global_pages=global_pages,
+            )
         new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool)
     elif cfg.sliding_window and cache["k"].shape[1] == cfg.sliding_window:
         # ---- rolling (sliding-window) cache ----
@@ -265,16 +274,20 @@ def attention_decode(
             out = jnp.einsum("bhd,hdm->bm", out, p["wo"].astype(dt))
             return out[:, None], dict(cache, k=k_c, v=v_c)
         slot = lengths % W
-        k_c = cache["k"].at[jnp.arange(B), slot].set(k1)
-        v_c = cache["v"].at[jnp.arange(B), slot].set(v1)
+        with scope("attn.kv_write"):
+            k_c = cache["k"].at[jnp.arange(B), slot].set(k1)
+            v_c = cache["v"].at[jnp.arange(B), slot].set(v1)
         valid = jnp.minimum(lengths + 1, W)
-        out = ops.decode_attention(q_rolling(q1, cfg), k_c, v_c, valid)
+        with scope("attn.kernel"):
+            out = ops.decode_attention(q_rolling(q1, cfg), k_c, v_c, valid)
         new_cache = dict(cache, k=k_c, v=v_c)
     else:
         # ---- contiguous cache ----
-        k_c = cache["k"].at[jnp.arange(B), lengths].set(k1)
-        v_c = cache["v"].at[jnp.arange(B), lengths].set(v1)
-        out = ops.decode_attention(q1, k_c, v_c, lengths + 1)
+        with scope("attn.kv_write"):
+            k_c = cache["k"].at[jnp.arange(B), lengths].set(k1)
+            v_c = cache["v"].at[jnp.arange(B), lengths].set(v1)
+        with scope("attn.kernel"):
+            out = ops.decode_attention(q1, k_c, v_c, lengths + 1)
         new_cache = dict(cache, k=k_c, v=v_c)
 
     out = jnp.einsum("bhd,hdm->bm", out, p["wo"].astype(dt))
@@ -310,26 +323,34 @@ def attention_chunk(
     owner); writes and the row gather address the flat pool directly.
     """
     dt = x.dtype
-    q, k, v = _project_qkv(p, x, cfg)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    scope = jax.named_scope  # the scopes of attention_decode
+    with scope("attn.qkv"):
+        q, k, v = _project_qkv(p, x, cfg)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     block = cache["k_pool"].shape[3]
-    kc = to_pages(k[0], block).astype(cache["k_pool"].dtype)  # (nc,Hkv,bl,D)
-    vc = to_pages(v[0], block).astype(cache["v_pool"].dtype)
-    if global_pages:
-        flat = (-1,) + cache["k_pool"].shape[2:]
-        kfl = cache["k_pool"].reshape(flat).at[pages].set(kc)
-        vfl = cache["v_pool"].reshape(flat).at[pages].set(vc)
-        gk = from_pages(kfl[row[:n_kv]])[None]  # (1, n_kv*block, Hkv, D)
-        gv = from_pages(vfl[row[:n_kv]])[None]
-        kp = kfl.reshape(cache["k_pool"].shape)
-        vp = vfl.reshape(cache["v_pool"].shape)
-    else:
-        kp = cache["k_pool"].at[slot, pages].set(kc)
-        vp = cache["v_pool"].at[slot, pages].set(vc)
-        gk = from_pages(kp[slot][row[:n_kv]])[None]
-        gv = from_pages(vp[slot][row[:n_kv]])[None]
-    out = ops.flash_attention(q, gk, gv, causal=True, q_offset=positions[0])
+    with scope("attn.kv_write"):
+        kc = to_pages(k[0], block).astype(cache["k_pool"].dtype)
+        vc = to_pages(v[0], block).astype(cache["v_pool"].dtype)
+        if global_pages:
+            flat = (-1,) + cache["k_pool"].shape[2:]
+            kfl = cache["k_pool"].reshape(flat).at[pages].set(kc)
+            vfl = cache["v_pool"].reshape(flat).at[pages].set(vc)
+            kp = kfl.reshape(cache["k_pool"].shape)
+            vp = vfl.reshape(cache["v_pool"].shape)
+        else:
+            kp = cache["k_pool"].at[slot, pages].set(kc)
+            vp = cache["v_pool"].at[slot, pages].set(vc)
+    # the kernel's scope holds the gather of the row's pages it reads
+    with scope("attn.kernel"):
+        if global_pages:
+            gk = from_pages(kfl[row[:n_kv]])[None]  # (1, n_kv*bl, Hkv, D)
+            gv = from_pages(vfl[row[:n_kv]])[None]
+        else:
+            gk = from_pages(kp[slot][row[:n_kv]])[None]
+            gv = from_pages(vp[slot][row[:n_kv]])[None]
+        out = ops.flash_attention(q, gk, gv, causal=True,
+                                  q_offset=positions[0])
     out = jnp.einsum("bshd,hdm->bsm", out, p["wo"].astype(dt))
     return out, dict(cache, k_pool=kp, v_pool=vp)
 

@@ -502,6 +502,16 @@ def forward_prefill(params, batch, cfg: ModelConfig, *,
     return logits_last, cache
 
 
+def _ffn(lp, h, cfg: ModelConfig):
+    """A decoder layer's feed-forward half (norm, MLP or MoE) under the
+    ``mlp`` named scope of the serving step."""
+    with jax.named_scope("mlp"):
+        m_in = L.apply_norm(lp["norm2"], h, cfg)
+        if cfg.family == "moe":
+            return L.apply_moe(lp["moe"], m_in, cfg)
+        return L.apply_mlp(lp["mlp"], m_in, cfg)
+
+
 def prefill_chunk(params, cache, batch, cfg: ModelConfig, *,
                   n_kv: Optional[int] = None,
                   global_pages: bool = False):
@@ -542,19 +552,15 @@ def prefill_chunk(params, cache, batch, cfg: ModelConfig, *,
             lp["attn"], a_in, cfg, cl, slot=slot, row=row, pages=pages,
             positions=positions, n_kv=n_kv, global_pages=global_pages)
         h = h + a
-        m_in = L.apply_norm(lp["norm2"], h, cfg)
-        if cfg.family == "moe":
-            m = L.apply_moe(lp["moe"], m_in, cfg)
-        else:
-            m = L.apply_mlp(lp["mlp"], m_in, cfg)
-        return h + m, new_c
+        return h + _ffn(lp, h, cfg), new_c
 
     x, new_layers = jax.lax.scan(body, x,
                                  (params["layers"], cache["layers"]))
     x = L.apply_norm(params["final_norm"], x, cfg)
     last = jnp.reshape(batch["last_index"], (1, 1, 1)).astype(jnp.int32)
     x_last = jnp.take_along_axis(x, last, axis=1)[:, 0]
-    logits = L.unembed(params["embed"], x_last, cfg)
+    with jax.named_scope("unembed"):
+        logits = L.unembed(params["embed"], x_last, cfg)
     return logits, dict(cache, layers=new_layers)
 
 
@@ -668,17 +674,13 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *,
                                           n_kv=n_kv,
                                           global_pages=global_pages)
             h = h + a
-            m_in = L.apply_norm(lp["norm2"], h, cfg)
-            if cfg.family == "moe":
-                m = L.apply_moe(lp["moe"], m_in, cfg)
-            else:
-                m = L.apply_mlp(lp["mlp"], m_in, cfg)
-            return h + m, new_c
+            return h + _ffn(lp, h, cfg), new_c
 
         x, new_layers = jax.lax.scan(body, x,
                                      (params["layers"], cache["layers"]))
         new_cache = dict(cache, layers=new_layers)
 
     x = L.apply_norm(params["final_norm"], x, cfg)
-    logits = L.unembed(params["embed"], x[:, 0], cfg)
+    with jax.named_scope("unembed"):
+        logits = L.unembed(params["embed"], x[:, 0], cfg)
     return logits, new_cache

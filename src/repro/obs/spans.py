@@ -18,13 +18,21 @@ the same trace row.
 
 Disabled recorders (built from a disabled registry) drop everything at
 the method guard — same zero-cost discipline as the tracer.
+
+:class:`StepPhases` is the engine's host phase clock: per named phase of
+an engine step a ``perf_counter_ns`` sum and a count, always on, and a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiled run
+shows each phase on the profiler's clock beside the device ops.
 """
 
 from __future__ import annotations
 
+import collections
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 #: canonical phase order for TTFT decomposition
 PHASES = ("queue", "prefill", "handoff", "decode")
@@ -133,3 +141,69 @@ class SpanRecorder:
 
     def to_dicts(self) -> List[dict]:
         return [s.to_dict() for s in self.spans]
+
+
+class _Phase:
+    """One open phase: the profiler annotation and the start time."""
+
+    __slots__ = ("clock", "name", "ann", "t0")
+
+    def __init__(self, clock: "StepPhases", name: str) -> None:
+        self.clock = clock
+        self.name = name
+        self.ann = TraceAnnotation(name)
+
+    def __enter__(self) -> None:
+        self.ann.__enter__()
+        if self.name == self.clock.root:
+            self.clock._waited = 0
+        self.t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self.ann.__exit__(*exc)
+        c, dt = self.clock, t1 - self.t0
+        c.ns[self.name] = c.ns.get(self.name, 0) + dt
+        c.count[self.name] = c.count.get(self.name, 0) + 1
+        if self.name in c.waits:
+            c._waited += dt
+        elif self.name == c.root:
+            c.own_ns += dt - c._waited
+            c.recent.append((t1 / 1e9, dt - c._waited))
+
+
+class StepPhases:
+    """Host phase clock of one engine.
+
+    ``with phases("serve.plan"): ...`` adds the block's wall time to
+    ``ns["serve.plan"]`` and one to ``count["serve.plan"]``, and opens a
+    profiler annotation of that name for the block.  Sums are inclusive:
+    a phase opened inside another counts in both.  The ``root`` phase
+    (the whole step) less the ``waits`` nested in it (blocking reads of
+    device results) is the host's own time: ``own_ns`` sums it over the
+    steps, and ``recent`` keeps it per step, with the step's end on
+    ``perf_counter`` seconds, for the last ``HISTORY`` steps, so that a
+    window of steps can be read without a profiler.
+    """
+
+    HISTORY = 4096
+
+    def __init__(self, root: str, waits: Sequence[str] = ()) -> None:
+        self.root = root
+        self.waits = frozenset(waits)
+        self.ns: Dict[str, int] = {}
+        self.count: Dict[str, int] = {}
+        self.own_ns = 0
+        self.recent: Deque[Tuple[float, int]] = collections.deque(
+            maxlen=self.HISTORY)
+        self._waited = 0  # wait time inside the open root phase
+
+    def __call__(self, name: str) -> _Phase:
+        return _Phase(self, name)
+
+    def seconds(self, name: str) -> float:
+        return self.ns.get(name, 0) / 1e9
+
+    def ms(self) -> Dict[str, float]:
+        """Phase -> total milliseconds."""
+        return {k: v / 1e6 for k, v in self.ns.items()}

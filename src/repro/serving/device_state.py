@@ -35,7 +35,6 @@ tests assert parity.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -43,6 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels.ref import to_pages
+from ..obs.spans import StepPhases
 
 
 def sample_tokens(logits, u, temperature: float, top_p: float):
@@ -115,7 +115,11 @@ class DeviceState:
         speculate_k: int = 0,
         draft_layers: int = 0,
         device: Optional[jax.Device] = None,
+        phases: Optional[StepPhases] = None,
     ) -> None:
+        """``phases`` is the engine's host phase clock: operand staging
+        and the fused step's enqueue are timed on it as ``serve.stage``
+        and ``serve.dispatch``."""
         self.model = model
         self.params = params
         self.cache = cache
@@ -186,7 +190,8 @@ class DeviceState:
         self._pending_resets: List[int] = []
         self._pending_admits: List[Tuple] = []
         self._pending_chunk: Optional[Tuple] = None
-        self.stage_ns = 0  # host time spent building step operands
+        self.phases = phases if phases is not None else StepPhases(
+            "serve.step")
 
         # dispatch accounting (decode plane vs admission plane).  Any
         # device call made on behalf of a decode step MUST bump
@@ -226,14 +231,19 @@ class DeviceState:
                  ck_seed, n_kv, has_chunk):
         B = self.max_slots
         rows = jnp.arange(B, dtype=jnp.int32)
+        # each part runs under a named scope (step.reset, step.chunk_lane,
+        # step.admit, step.grow, step.decode, step.sample), so every
+        # device op carries its part in the profiler's name stack
+        scope = jax.named_scope
 
         # 1. slot resets (requests finished since the last dispatch)
-        keep = 1 - reset_m
-        lengths = lengths * keep
-        mask = mask * keep
-        pages = pages * keep
-        seeds = seeds * keep
-        table = table * keep[:, None]
+        with scope("step.reset"):
+            keep = 1 - reset_m
+            lengths = lengths * keep
+            mask = mask * keep
+            pages = pages * keep
+            seeds = seeds * keep
+            table = table * keep[:, None]
 
         # 1b. chunked-prefill lane (at most ONE chunk per step; static
         # branch, so decode-only steps compile without it).  The chunk's
@@ -244,53 +254,59 @@ class DeviceState:
         # device-side transition, still one dispatch.
         chunk_first = self._zero
         if has_chunk:
-            ck_logits, cache = self.model.prefill_chunk(
-                params, cache,
-                {"tokens": ck_tokens, "start": ck_start, "slot": ck_slot,
-                 "row": ck_row, "pages": ck_pages,
-                 "last_index": ck_last_index},
-                n_kv=n_kv, global_pages=self.global_pages,
-            )
-            if self.temperature > 0.0:
-                # token 1 lands at sequence index start + last_index + 1
-                # (== the prompt length, on the final chunk)
-                u = counter_uniform(ck_seed,
-                                    ck_start + ck_last_index + 1)[None]
-                first = sample_tokens(ck_logits, u, self.temperature,
-                                      self.top_p)
-            else:
-                first = jnp.argmax(ck_logits, axis=-1).astype(jnp.int32)
-            chunk_first = first[0]
-            first_buf = jnp.where(ck_last == 1,
-                                  first_buf.at[ck_slot].set(chunk_first),
-                                  first_buf)
+            with scope("step.chunk_lane"):
+                ck_logits, cache = self.model.prefill_chunk(
+                    params, cache,
+                    {"tokens": ck_tokens, "start": ck_start,
+                     "slot": ck_slot, "row": ck_row, "pages": ck_pages,
+                     "last_index": ck_last_index},
+                    n_kv=n_kv, global_pages=self.global_pages,
+                )
+                if self.temperature > 0.0:
+                    # token 1 lands at sequence index start + last_index
+                    # + 1 (== the prompt length, on the final chunk)
+                    u = counter_uniform(ck_seed,
+                                        ck_start + ck_last_index + 1)[None]
+                    first = sample_tokens(ck_logits, u, self.temperature,
+                                          self.top_p)
+                else:
+                    first = jnp.argmax(ck_logits, axis=-1).astype(
+                        jnp.int32)
+                chunk_first = first[0]
+                first_buf = jnp.where(
+                    ck_last == 1, first_buf.at[ck_slot].set(chunk_first),
+                    first_buf)
 
-        # 2. admissions
-        lengths = jnp.where(admit_m == 1, admit_len, lengths)
-        table = jnp.where(admit_m[:, None] == 1, admit_row, table)
-        mask = jnp.maximum(mask, admit_m)
-        pages = jnp.where(admit_m == 1, admit_pages, pages)
-        seeds = jnp.where(admit_m == 1, admit_seed, seeds)
-        first = jnp.where(admit_from_buf == 1, first_buf, admit_tok)
-        tokens = jnp.where(admit_set_tok[:, None] == 1, first[:, None],
-                           tokens)
+        with scope("step.admit"):
+            # 2. admissions
+            lengths = jnp.where(admit_m == 1, admit_len, lengths)
+            table = jnp.where(admit_m[:, None] == 1, admit_row, table)
+            mask = jnp.maximum(mask, admit_m)
+            pages = jnp.where(admit_m == 1, admit_pages, pages)
+            seeds = jnp.where(admit_m == 1, admit_seed, seeds)
+            first = jnp.where(admit_from_buf == 1, first_buf, admit_tok)
+            tokens = jnp.where(admit_set_tok[:, None] == 1, first[:, None],
+                               tokens)
 
-        # 3. teacher-forced suffix overrides (prefix-cache replay)
-        tokens = jnp.where(tf_m[:, None] == 1, tf_vals[:, None], tokens)
+            # 3. teacher-forced suffix overrides (prefix-cache replay)
+            tokens = jnp.where(tf_m[:, None] == 1, tf_vals[:, None],
+                               tokens)
 
         # 4. device-side page growth: the need mask comes from the
         # DEVICE lengths; the host only supplied per-slot candidates.
         # The speculative lane writes up to `speculate_k` positions past
         # `lengths` this dispatch, so the horizon extends by k — still at
         # most ONE page per slot per dispatch because k < block.
-        look = self.speculate_k
-        need = ((mask == 1)
-                & (((lengths + look) // self.block + 1) > pages)
-                & (pages < self.mb))
-        pos = jnp.clip(pages, 0, self.mb - 1)
-        cur = table[rows, pos]
-        table = table.at[rows, pos].set(jnp.where(need, cand_pages, cur))
-        pages = pages + need.astype(jnp.int32)
+        with scope("step.grow"):
+            look = self.speculate_k
+            need = ((mask == 1)
+                    & (((lengths + look) // self.block + 1) > pages)
+                    & (pages < self.mb))
+            pos = jnp.clip(pages, 0, self.mb - 1)
+            cur = table[rows, pos]
+            table = table.at[rows, pos].set(
+                jnp.where(need, cand_pages, cur))
+            pages = pages + need.astype(jnp.int32)
 
         gp = self.global_pages
         if self.speculate_k > 0:
@@ -300,50 +316,54 @@ class DeviceState:
             # the copy is discarded — verify rewrites identical KV).
             k = self.speculate_k
             dl = self.draft_layers
-            dparams = dict(params, layers=jax.tree.map(
-                lambda a: a[:dl], params["layers"]))
-            dcache = dict(cache, layers=jax.tree.map(
-                lambda a: a[:dl], cache["layers"]))
-            d_tok, d_len, drafts = tokens, lengths, []
-            for _ in range(k):
-                d_logits, dcache = self.model.decode_step(
-                    dparams, dcache,
-                    {"tokens": d_tok, "lengths": d_len,
-                     "block_table": table},
-                    n_kv=n_kv, global_pages=gp,
-                )
-                nxt = jnp.argmax(d_logits, axis=-1).astype(jnp.int32)
-                drafts.append(nxt)
-                d_tok = nxt[:, None]
-                d_len = d_len + 1
-            # Verify: k+1 full steps teacher-forcing [t0, d1..dk] into
-            # the REAL cache; v_i is the target model's token for
-            # position lengths+i+1 — bit-identical to what non-
-            # speculative greedy decode would produce there.
-            v_tok, v_len, v_list = tokens, lengths, []
-            for i in range(k + 1):
-                logits, cache = self.model.decode_step(
-                    params, cache,
-                    {"tokens": v_tok, "lengths": v_len,
-                     "block_table": table},
-                    n_kv=n_kv, global_pages=gp,
-                )
-                v_list.append(
-                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
-                if i < k:
-                    v_tok = drafts[i][:, None]
-                    v_len = v_len + 1
-            v = jnp.stack(v_list, axis=1)   # (B, k+1)
-            d = jnp.stack(drafts, axis=1)   # (B, k)
-            # accept the longest prefix of drafts the target agrees with;
-            # counts = accepted + 1 (the verify chain's bonus token).
-            # Slots mid teacher-forcing advance exactly 1 like a plain
-            # step (their "drafts" are junk — the forced token overrides).
-            acc = jnp.cumprod((d == v[:, :k]).astype(jnp.int32), axis=1)
-            spec_m = (mask == 1) & (tf_m == 0)
-            counts = jnp.where(spec_m, acc.sum(axis=1) + 1, 1)
-            new_tokens = jnp.take_along_axis(
-                v, counts[:, None] - 1, axis=1)[:, 0]
+            with scope("step.decode"):
+                dparams = dict(params, layers=jax.tree.map(
+                    lambda a: a[:dl], params["layers"]))
+                dcache = dict(cache, layers=jax.tree.map(
+                    lambda a: a[:dl], cache["layers"]))
+                d_tok, d_len, drafts = tokens, lengths, []
+                for _ in range(k):
+                    d_logits, dcache = self.model.decode_step(
+                        dparams, dcache,
+                        {"tokens": d_tok, "lengths": d_len,
+                         "block_table": table},
+                        n_kv=n_kv, global_pages=gp,
+                    )
+                    nxt = jnp.argmax(d_logits, axis=-1).astype(jnp.int32)
+                    drafts.append(nxt)
+                    d_tok = nxt[:, None]
+                    d_len = d_len + 1
+                # Verify: k+1 full steps teacher-forcing [t0, d1..dk]
+                # into the REAL cache; v_i is the target model's token
+                # for position lengths+i+1 — bit-identical to what non-
+                # speculative greedy decode would produce there.
+                v_tok, v_len, v_list = tokens, lengths, []
+                for i in range(k + 1):
+                    logits, cache = self.model.decode_step(
+                        params, cache,
+                        {"tokens": v_tok, "lengths": v_len,
+                         "block_table": table},
+                        n_kv=n_kv, global_pages=gp,
+                    )
+                    v_list.append(
+                        jnp.argmax(logits, axis=-1).astype(jnp.int32))
+                    if i < k:
+                        v_tok = drafts[i][:, None]
+                        v_len = v_len + 1
+            with scope("step.sample"):
+                v = jnp.stack(v_list, axis=1)   # (B, k+1)
+                d = jnp.stack(drafts, axis=1)   # (B, k)
+                # accept the longest prefix of drafts the target agrees
+                # with; counts = accepted + 1 (the verify chain's bonus
+                # token).  Slots mid teacher-forcing advance exactly 1
+                # like a plain step (their "drafts" are junk — the
+                # forced token overrides).
+                acc = jnp.cumprod((d == v[:, :k]).astype(jnp.int32),
+                                  axis=1)
+                spec_m = (mask == 1) & (tf_m == 0)
+                counts = jnp.where(spec_m, acc.sum(axis=1) + 1, 1)
+                new_tokens = jnp.take_along_axis(
+                    v, counts[:, None] - 1, axis=1)[:, 0]
             # rejected drafts' KV (positions lengths+counts..lengths+k)
             # stays garbage but is never attended: lengths advance by
             # counts, and later steps overwrite those offsets before any
@@ -353,23 +373,26 @@ class DeviceState:
                     v, counts * mask)
 
         # 5. decode
-        logits, cache = self.model.decode_step(
-            params, cache,
-            {"tokens": tokens, "lengths": lengths, "block_table": table},
-            n_kv=n_kv, global_pages=gp,
-        )
+        with scope("step.decode"):
+            logits, cache = self.model.decode_step(
+                params, cache,
+                {"tokens": tokens, "lengths": lengths,
+                 "block_table": table},
+                n_kv=n_kv, global_pages=gp,
+            )
 
-        # 6. sample (greedy is the statically-compiled temperature=0 path).
-        # The token this dispatch emits lands at sequence index
+        # 6. sample (greedy is the statically-compiled temperature=0
+        # path).  The token this dispatch emits lands at sequence index
         # lengths + 1 (index `lengths` holds the token being consumed),
         # so its uniform is counter_uniform(slot key, lengths + 1) —
         # position-keyed, engine-independent.
-        if self.temperature > 0.0:
-            u = jax.vmap(counter_uniform)(seeds, lengths + 1)
-            new_tokens = sample_tokens(logits, u, self.temperature,
-                                       self.top_p)
-        else:
-            new_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with scope("step.sample"):
+            if self.temperature > 0.0:
+                u = jax.vmap(counter_uniform)(seeds, lengths + 1)
+                new_tokens = sample_tokens(logits, u, self.temperature,
+                                           self.top_p)
+            else:
+                new_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (new_tokens[:, None], cache, lengths + mask, table, mask,
                 pages, first_buf, seeds, chunk_first)
 
@@ -569,12 +592,11 @@ class DeviceState:
                    need mask selects the slot; host and device agree by
                    construction, see module docstring).
         """
-        t0 = time.perf_counter_ns()
         has_chunk = self._pending_chunk is not None
-        operands = self._operands(tf, grow)
-        self.stage_ns += time.perf_counter_ns() - t0
-
-        out = self._step(*operands, n_kv, has_chunk)
+        with self.phases("serve.stage"):
+            operands = self._operands(tf, grow)
+        with self.phases("serve.dispatch"):
+            out = self._step(*operands, n_kv, has_chunk)
         spec = None
         if self.speculate_k > 0:
             (self.tokens, self.cache, self.lengths, self.table, self.mask,

@@ -41,9 +41,15 @@ from ..memory.prefix_cache import PrefixCache, block_key, prefix_block_keys
 from ..models import Model
 from ..models.transformer import BLOCK_SIZE, cache_layout
 from ..obs.metrics import Registry, apply_aliases
-from ..obs.spans import SpanRecorder
+from ..obs.spans import SpanRecorder, StepPhases
 from .device_state import DeviceState
 from .scheduler import ForkGroup, Request, Scheduler
+
+
+#: the host phase clock's whole step, and the blocking token read-back
+#: its own time leaves out (phase names: docs/observability.md)
+STEP = "serve.step"
+READ_BACK_WAIT = "serve.read_back.wait"
 
 
 def _pow2_bucket(n: int) -> int:
@@ -161,6 +167,10 @@ class ServingEngine:
         self.obs = self.pool.trace.registry
         self.spans = (spans if spans is not None
                       else SpanRecorder(enabled=self.obs.enabled))
+        # host phase clock: per-phase perf_counter sums, always on, and
+        # profiler annotations of the same names (the device plane times
+        # its staging and dispatch on it too)
+        self.phases = StepPhases(STEP, waits=(READ_BACK_WAIT,))
         for s in range(max_slots):
             got = self.pool.alloc(s, 1)
             assert got == [0], "page 0 must be the scratch page"
@@ -175,6 +185,7 @@ class ServingEngine:
             seed=sample_seed, chunk_tokens=chunk_tokens,
             global_pages=True, speculate_k=speculate_k,
             draft_layers=self.draft_layers, device=device,
+            phases=self.phases,
         )
 
         # page-ref cache: rebuilt only when the active page set changes
@@ -185,10 +196,6 @@ class ServingEngine:
         self.decode_steps = 0  # engine steps that dispatched decode work
         self.admissions = 0  # requests admitted
         self.prefill_chunks = 0  # chunk-lane rides (chunked admissions)
-        self.host_ns = 0  # host-side bookkeeping time in _dispatch_decode
-        self.busy_s = 0.0  # cumulative step() wall time: this replica's
-        # own busy clock — in a cluster the serial tick sums every
-        # replica's dispatches, so per-replica latency reads THIS clock
         self.backpressure_syncs = 0  # PoolExhausted -> force-sync events
         self.chunk_backpressure = 0  # ... of which mid chunked prefill
         # chunk-lane per-step state (consumed by _dispatch_decode)
@@ -228,6 +235,13 @@ class ServingEngine:
     @property
     def _inflight(self):
         return self.sched.inflight
+
+    @property
+    def busy_s(self) -> float:
+        """Cumulative step() wall time (perf_counter): this replica's own
+        busy clock — in a cluster the serial tick sums every replica's
+        dispatches, so per-replica latency reads THIS clock."""
+        return self.phases.seconds(STEP)
 
     # ------------------------------------------------------------------
     # public API
@@ -382,27 +396,33 @@ class ServingEngine:
                 f"replica {self.replica_id} is crashed (fault injection)"
             )
         self.steps += 1
-        _t0 = time.time()
+        with self.phases(STEP):
+            self._step()
+
+    def _step(self) -> None:
         # 1. retire the oldest in-flight step if the pipeline is full
         while self.sched.pipeline_full():
             self._complete_oldest()
         # 2. admissions (chunked admissions only OCCUPY a slot here;
         #    their prompt tokens ride the fused step one chunk at a time;
         #    a draining replica pauses here and only finishes what it has)
-        while (not self.sched.admissions_paused and self.sched.waiting
-               and self.sched.free_slots):
-            if not self._admit(self.sched.waiting[0]):
-                break
-            self.sched.waiting.popleft()
+        with self.phases("serve.admit"):
+            while (not self.sched.admissions_paused and self.sched.waiting
+                   and self.sched.free_slots):
+                if not self._admit(self.sched.waiting[0]):
+                    break
+                self.sched.waiting.popleft()
         # 3. advance at most one prefill chunk (round-robin over the
         #    admitting slots — the interleaving policy)
-        chunk_staged = bool(self.sched.admitting) and self._advance_chunk()
+        chunk_staged = False
+        if self.sched.admitting:
+            with self.phases("serve.chunk"):
+                chunk_staged = self._advance_chunk()
         # 4. one fused dispatch for decode work and/or the staged chunk
         if self.sched.active or chunk_staged:
             self._dispatch_decode()
         elif self.sched.inflight:
             self._complete_oldest()
-        self.busy_s += time.time() - _t0
 
     def drain(self) -> None:
         while self.sched.inflight:
@@ -897,68 +917,14 @@ class ServingEngine:
     # decode dispatch (ONE fused device call)
     # ------------------------------------------------------------------
     def _dispatch_decode(self) -> None:
-        t0 = time.perf_counter_ns()
-        sched = self.sched
-        # page growth: the DEVICE decides via its lengths; the host runs
-        # the same deterministic rule on its mirror to pop the free-list
-        # candidates the device will consume, and to detect exhaustion
-        # (back-pressure) BEFORE dispatch
-        grow: Dict[int, int] = {}
-        # snapshot: the back-pressure force-sync below may _finish (and
-        # remove from active) any request, including this one
-        for slot, req in list(sched.active.items()):
-            # lookahead: the speculative lane writes KV up to k positions
-            # past the current length inside ONE dispatch, so the page
-            # horizon extends by k (still at most one new page per
-            # dispatch: accepted counts <= k + 1 <= block)
-            need = min(
-                (int(sched.lengths[slot]) + self.speculate_k)
-                // self.block + 1,
-                self.mb,
-            )
-            if req.done or req.n_pages >= need:
-                continue
-            assert need - req.n_pages == 1, "mirror drifted from device"
-            try:
-                (page,) = self.pool.alloc(slot, 1)
-            except PoolExhausted:
-                # back-pressure: force-sync everything — cycling any
-                # open chunk holds first, so their parked retires can
-                # actually reclaim — and retry once (device wait — keep
-                # it out of the host-ns timer)
-                self.backpressure_syncs += 1
-                self.host_ns += time.perf_counter_ns() - t0
-                self._cycle_chunk_holds()
-                t0 = time.perf_counter_ns()
-                if req.done:
-                    continue  # force-sync finished this very request
-                (page,) = self.pool.alloc(slot, 1)
-            grow[slot] = sched.append_page(slot, page)
-            self._refs_dirty = True
-        if not sched.active and not self.dev.has_pending_chunk():
+        with self.phases("serve.plan"):
+            plan = self._plan_dispatch()
+        if plan is None:
             return  # every active request finished during force-sync
-
-        # teacher-forced suffix tokens (prefix-cache admissions) override
-        # the sampled token chain for their slots
-        tf: Dict[int, int] = {}
-        for slot, req in sched.active.items():
-            suffix = getattr(req, "_tf_suffix", [])
-            if suffix:
-                tf[slot] = suffix.pop(0)
-
-        if self._refs_dirty:
-            self._page_refs = sched.page_refs()
-            self._refs_dirty = False
-
-        # bucketed bound on the KV sweep: pages any active sequence — or
-        # the staged prefill chunk's gather — can touch this step
-        # (power-of-two bucket caps recompiles)
-        n_need = max(sched.max_need_pages(self.speculate_k),
-                     self._chunk_need_pages, 1)
-        n_kv = min(max(_pow2_bucket(n_need), 1), self.mb)
-        self.host_ns += time.perf_counter_ns() - t0
-
-        stamp = self.pool.begin_step(self._page_refs)
+        tf, grow, n_kv = plan
+        sched = self.sched
+        with self.phases("serve.policy.begin"):
+            stamp = self.pool.begin_step(self._page_refs)
         tokens, chunk_first, spec = self.dev.dispatch(tf, grow, n_kv)
         if self._chunk_finalizing is not None:
             # the final chunk's on-device first-token sample; the host
@@ -989,20 +955,87 @@ class ServingEngine:
         else:
             sched.advance_lengths()
 
+    def _plan_dispatch(self):
+        """The host bookkeeping before a fused dispatch: page growth,
+        teacher-forced tokens, page refs and the KV-sweep bucket.
+        Returns ``(tf, grow, n_kv)``, or None when nothing is left to
+        dispatch."""
+        sched = self.sched
+        # page growth: the DEVICE decides via its lengths; the host runs
+        # the same deterministic rule on its mirror to pop the free-list
+        # candidates the device will consume, and to detect exhaustion
+        # (back-pressure) BEFORE dispatch
+        grow: Dict[int, int] = {}
+        # snapshot: the back-pressure force-sync below may _finish (and
+        # remove from active) any request, including this one
+        for slot, req in list(sched.active.items()):
+            # lookahead: the speculative lane writes KV up to k positions
+            # past the current length inside ONE dispatch, so the page
+            # horizon extends by k (still at most one new page per
+            # dispatch: accepted counts <= k + 1 <= block)
+            need = min(
+                (int(sched.lengths[slot]) + self.speculate_k)
+                // self.block + 1,
+                self.mb,
+            )
+            if req.done or req.n_pages >= need:
+                continue
+            assert need - req.n_pages == 1, "mirror drifted from device"
+            try:
+                (page,) = self.pool.alloc(slot, 1)
+            except PoolExhausted:
+                # back-pressure: force-sync everything — cycling any
+                # open chunk holds first, so their parked retires can
+                # actually reclaim — and retry once (its device waits
+                # count as serve.read_back.wait)
+                self.backpressure_syncs += 1
+                self._cycle_chunk_holds()
+                if req.done:
+                    continue  # force-sync finished this very request
+                (page,) = self.pool.alloc(slot, 1)
+            grow[slot] = sched.append_page(slot, page)
+            self._refs_dirty = True
+        if not sched.active and not self.dev.has_pending_chunk():
+            return None
+
+        # teacher-forced suffix tokens (prefix-cache admissions) override
+        # the sampled token chain for their slots
+        tf: Dict[int, int] = {}
+        for slot, req in sched.active.items():
+            suffix = getattr(req, "_tf_suffix", [])
+            if suffix:
+                tf[slot] = suffix.pop(0)
+
+        if self._refs_dirty:
+            self._page_refs = sched.page_refs()
+            self._refs_dirty = False
+
+        # bucketed bound on the KV sweep: pages any active sequence — or
+        # the staged prefill chunk's gather — can touch this step
+        # (power-of-two bucket caps recompiles)
+        n_need = max(sched.max_need_pages(self.speculate_k),
+                     self._chunk_need_pages, 1)
+        n_kv = min(max(_pow2_bucket(n_need), 1), self.mb)
+        return tf, grow, n_kv
+
     # ------------------------------------------------------------------
     # completion (the pipeline-lagged sync point)
     # ------------------------------------------------------------------
     def _complete_oldest(self) -> None:
         if not self.sched.inflight:
             return
-        stamp, tokens_dev, active, lengths_snap, spec = (
-            self.sched.inflight.popleft()
-        )
-        tokens = np.asarray(jax.device_get(tokens_dev))  # sync point
-        if spec is not None:
-            v = np.asarray(jax.device_get(spec[0]))  # verifier chain
-            counts = np.asarray(jax.device_get(spec[1]))  # accepted + 1
-        self.pool.complete_step(stamp)
+        with self.phases("serve.read_back"):
+            self._read_back(*self.sched.inflight.popleft())
+
+    def _read_back(self, stamp, tokens_dev, active, lengths_snap,
+                   spec) -> None:
+        with self.phases(READ_BACK_WAIT):
+            tokens = np.asarray(jax.device_get(tokens_dev))  # sync point
+            if spec is not None:
+                v = np.asarray(jax.device_get(spec[0]))  # verifier chain
+                counts = np.asarray(jax.device_get(spec[1]))  # accepted+1
+        with self.phases("serve.policy.complete"):
+            self.pool.complete_step(stamp)
         if spec is not None:
             # the device advanced each slot by its accepted count; the
             # mirror follows the observed counts (the ONE place the spec
@@ -1147,12 +1180,13 @@ class ServingEngine:
             "finished": len(self.sched.finished),
             "admissions": self.admissions,
             "free_pages": self.pool.free_pages_total(),
-            # includes the device plane's operand-staging time so the
-            # fused step's host cost is measured, not hidden
+            # the host's own time per step: all of serve.step but the
+            # blocking token read-back (policy, staging and dispatch
+            # included); phase_ms has every phase's total
             "host_us_per_step": (
-                (self.host_ns + self.dev.stage_ns) / 1e3
-                / max(self.steps, 1)
+                self.phases.own_ns / 1e3 / max(self.steps, 1)
             ),
+            "phase_ms": self.phases.ms(),
             # numerator tracked by the device plane, denominator by the
             # engine: a reintroduced per-step scatter shows up as > 1
             "dispatches_per_step": (

@@ -2,11 +2,14 @@
 at qwen2-0.5b widths (14 heads, 2 kv heads, head_dim 64, bf16, 128-token
 pages).  Nothing runs: the TPU compiler rejects here what the chip would
 reject (block shapes off the (8, 128) tiling, values captured by a kernel).
+Each custom call the compiler emits carries its kernel's stable name
+(``pallas_call(name=...)``), the name its events have in a profile.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file loads the TPU compiler."""
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import ARCHS, ShapeConfig
+from repro.kernels.block_gather import block_gather_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import (
     decode_attention_pallas,
@@ -55,6 +59,14 @@ def compiled_text(fn, *avals) -> str:
     return jax.jit(fn).lower(*avals).compile().as_text()
 
 
+def kernels(text: str) -> set:
+    """The names of the compiled program's Pallas custom calls, without
+    the numeric suffix the compiler adds."""
+    return {m.group(1) for m in re.finditer(
+        r"%([A-Za-z_][\w-]*?)(?:\.\d+)* = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text)}
+
+
 @pytest.mark.parametrize("global_pages", [True, False])
 def test_paged_attention_compiles(one_chip, global_pages):
     pool = spec(one_chip, (SLOTS, N_POOL, HKV, BLOCK, D))
@@ -65,7 +77,7 @@ def test_paged_attention_compiles(one_chip, global_pages):
         spec(one_chip, (SLOTS, N_POOL), jnp.int32),
         spec(one_chip, (SLOTS,), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"paged_attention"}
 
 
 def test_flash_attention_traced_q_offset_compiles(one_chip):
@@ -76,7 +88,7 @@ def test_flash_attention_traced_q_offset_compiles(one_chip):
         spec(one_chip, (1, BLOCK, H, D)), kv, kv,
         spec(one_chip, (), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"flash_attention"}
 
 
 def test_decode_attention_compiles(one_chip):
@@ -85,7 +97,15 @@ def test_decode_attention_compiles(one_chip):
         decode_attention_pallas, spec(one_chip, (SLOTS, H, D)), kv, kv,
         spec(one_chip, (SLOTS,), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"decode_attention"}
+
+
+def test_block_gather_compiles(one_chip):
+    text = compiled_text(
+        block_gather_pallas, spec(one_chip, (N_POOL, HKV, BLOCK, D)),
+        spec(one_chip, (4,), jnp.int32),
+    )
+    assert kernels(text) == {"block_gather"}
 
 
 @pytest.mark.parametrize("entry", ["decode_step", "prefill_chunk"])
@@ -116,4 +136,5 @@ def test_served_model_entry_compiles_with_kernels(one_chip, entry,
                  "pages": i32((1,)), "last_index": i32(())}
     text = compiled_text(lambda p, c, b: fn(p, c, b)[0], params, cache,
                          batch)
-    assert "tpu_custom_call" in text
+    assert kernels(text) == {"decode_step": {"paged_attention"},
+                             "prefill_chunk": {"flash_attention"}}[entry]
