@@ -130,22 +130,77 @@ def decode_attention(q, k_cache, v_cache, lengths, *,
 
 
 def paged_attention(q, k_pool, v_pool, block_table, lengths, *,
+                    layer=None,
                     n_kv: Optional[int] = None,
                     global_pages: bool = False,
                     impl: Optional[str] = None):
-    """``n_kv`` statically bounds the KV-page sweep (see the Pallas
-    kernel's docstring); ``None`` sweeps the full table width.
-    ``global_pages`` switches table entries to slot-flattened GLOBAL page
-    ids (``slot * N_pool + page``) so rows may reference pages owned by
-    other slots — the copy-on-write fork substrate."""
+    """``layer`` selects one layer of stacked ``(L, ...)`` pools; ``None``
+    takes one layer's pools.  ``n_kv`` statically bounds the KV-page
+    sweep (see the Pallas kernel's docstring); ``None`` sweeps the full
+    table width.  ``global_pages`` switches table entries to
+    slot-flattened GLOBAL page ids (``slot * N_pool + page``) so rows may
+    reference pages owned by other slots — the copy-on-write fork
+    substrate."""
     mode = _impl(impl)
     if mode == "ref":
         return ref.paged_attention(q, k_pool, v_pool, block_table, lengths,
-                                   n_kv=n_kv, global_pages=global_pages)
+                                   layer=layer, n_kv=n_kv,
+                                   global_pages=global_pages)
     from .paged_attention import paged_attention_pallas
 
     return paged_attention_pallas(
-        q, k_pool, v_pool, block_table, lengths, n_kv=n_kv,
+        q, k_pool, v_pool, block_table, lengths, layer=layer, n_kv=n_kv,
+        global_pages=global_pages, interpret=(mode == "interpret"),
+    )
+
+
+def write_kv_rows(k_pool, v_pool, k_new, v_new, pages, offs, *,
+                  layer=None, global_pages: bool = False,
+                  impl: Optional[str] = None):
+    """Write one token row per slot into (stacked) paged pools, in place
+    on the TPU; see :func:`repro.kernels.ref.write_rows`."""
+    mode = _impl(impl)
+    if mode == "ref":
+        return ref.write_rows(k_pool, v_pool, k_new, v_new, pages, offs,
+                              layer=layer, global_pages=global_pages)
+    from .paged_attention import write_rows_pallas
+
+    return write_rows_pallas(
+        k_pool, v_pool, k_new, v_new, pages, offs, layer=layer,
+        global_pages=global_pages, interpret=(mode == "interpret"),
+    )
+
+
+def write_kv_pages(k_pool, v_pool, k_pages, v_pages, pages, *, slot,
+                   layer=None, global_pages: bool = False,
+                   impl: Optional[str] = None):
+    """Write whole pages of one slot into (stacked) paged pools, in place
+    on the TPU; see :func:`repro.kernels.ref.write_pages`."""
+    mode = _impl(impl)
+    if mode == "ref":
+        return ref.write_pages(k_pool, v_pool, k_pages, v_pages, pages,
+                               slot=slot, layer=layer,
+                               global_pages=global_pages)
+    from .paged_attention import write_pages_pallas
+
+    return write_pages_pallas(
+        k_pool, v_pool, k_pages, v_pages, pages, slot=slot, layer=layer,
+        global_pages=global_pages, interpret=(mode == "interpret"),
+    )
+
+
+def read_kv_pages(k_pool, v_pool, pages, *, slot, layer=None,
+                  global_pages: bool = False, impl: Optional[str] = None):
+    """Gather whole pages of one slot out of (stacked) paged pools; see
+    :func:`repro.kernels.ref.read_pages`."""
+    mode = _impl(impl)
+    if mode == "ref":
+        return ref.read_pages(k_pool, v_pool, pages, slot=slot, layer=layer,
+                              global_pages=global_pages)
+    from .paged_attention import read_pages_pallas
+
+    return read_pages_pallas(
+        k_pool, v_pool, pages, slot=slot, layer=layer,
         global_pages=global_pages, interpret=(mode == "interpret"),
     )
 

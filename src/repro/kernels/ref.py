@@ -173,10 +173,14 @@ def paged_attention(
     block_table: jax.Array,  # (B, max_blocks) int32 — local page ids
     lengths: jax.Array,      # (B,) int32
     *,
+    layer=None,
     n_kv: Optional[int] = None,
     global_pages: bool = False,
 ) -> jax.Array:
     """Oracle: gather the pages then run decode attention.
+
+    ``layer`` indexes stacked pools ``(L, ...)`` first (``None``: the
+    pools are one layer's).
 
     Pools are per-sequence-local (pages of a sequence live on the shard
     that owns the sequence — the TPU adaptation of vLLM's global pool; see
@@ -193,11 +197,12 @@ def paged_attention(
     past-length positions mask to exp-underflow zero either way, so any
     bound >= ceil(max(lengths)/block) is bit-identical to the full sweep.
     """
+    if layer is not None:
+        k_pool, v_pool = k_pool[layer], v_pool[layer]
     if n_kv is not None and n_kv < block_table.shape[1]:
         block_table = block_table[:, :n_kv]
-    B = q.shape[0]
     if global_pages:
-        flat = (B * k_pool.shape[1],) + k_pool.shape[2:]
+        flat = (-1,) + k_pool.shape[-3:]  # slot-flattened already or not
         k = jnp.take(k_pool.reshape(flat), block_table, axis=0)
         v = jnp.take(v_pool.reshape(flat), block_table, axis=0)
     else:
@@ -206,6 +211,66 @@ def paged_attention(
         v = jnp.take_along_axis(v_pool, idx, axis=1)
     # (B, MB, Hkv, block, D) -> (B, MB*block, Hkv, D)
     return decode_attention(q, from_pages(k), from_pages(v), lengths)
+
+
+def _stacked(k_pool, v_pool, layer, global_pages):
+    """``(k, v, layer)``: the pools as a stack ``(L, ...)`` (a stack of
+    one where ``layer`` is None), slot-flattened ``(L, B * N_blocks, Hkv,
+    block, D)`` with ``global_pages``."""
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    if global_pages:
+        flat = (k_pool.shape[0], -1) + k_pool.shape[-3:]
+        k_pool, v_pool = k_pool.reshape(flat), v_pool.reshape(flat)
+    return k_pool, v_pool, layer
+
+
+def write_rows(
+    k_pool: jax.Array,  # (L, B, N_blocks, Hkv, block, D) stacked pools
+    v_pool: jax.Array,
+    k_new: jax.Array,   # (B, Hkv, D) one token row per slot
+    v_new: jax.Array,
+    pages: jax.Array,   # (B,) int32 page of each slot's row
+    offs: jax.Array,    # (B,) int32 token offset in that page
+    *,
+    layer=None,
+    global_pages: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """Oracle of the in-place row write: slot b's row lands in
+    ``pages[b]`` (a global id into the slot-flattened pool with
+    ``global_pages``, else slot b's own page) of layer ``layer`` (``None``:
+    the pools are one layer's) at token ``offs[b]``."""
+    shape = k_pool.shape
+    k, v, layer = _stacked(k_pool, v_pool, layer, global_pages)
+    if global_pages:
+        at = (layer, pages, slice(None), offs)
+    else:
+        at = (layer, jnp.arange(k_new.shape[0]), pages, slice(None), offs)
+    k = k.at[at].set(k_new.astype(k.dtype))
+    v = v.at[at].set(v_new.astype(v.dtype))
+    return k.reshape(shape), v.reshape(shape)
+
+
+def write_pages(k_pool, v_pool, k_pages, v_pages, pages, *, slot,
+                layer=None, global_pages: bool = False):
+    """Oracle of the in-place page write: ``k_pages``/``v_pages`` ``(n,
+    Hkv, block, D)`` land in slot ``slot``'s ``pages`` (global ids with
+    ``global_pages``) of layer ``layer``."""
+    shape = k_pool.shape
+    k, v, layer = _stacked(k_pool, v_pool, layer, global_pages)
+    at = (layer, pages) if global_pages else (layer, slot, pages)
+    k = k.at[at].set(k_pages.astype(k.dtype))
+    v = v.at[at].set(v_pages.astype(v.dtype))
+    return k.reshape(shape), v.reshape(shape)
+
+
+def read_pages(k_pool, v_pool, pages, *, slot, layer=None,
+               global_pages: bool = False):
+    """Slot ``slot``'s ``pages`` of layer ``layer``, in order: ``(n,
+    Hkv, block, D)`` of K and of V."""
+    k, v, layer = _stacked(k_pool, v_pool, layer, global_pages)
+    at = (layer, pages) if global_pages else (layer, slot, pages)
+    return k[at], v[at]
 
 
 # ---------------------------------------------------------------------------

@@ -166,6 +166,7 @@ def attention_decode(
     block_table: Optional[jax.Array] = None,  # (B, max_blocks) for paged
     n_kv: Optional[int] = None,  # static bound on the paged KV sweep
     global_pages: bool = False,  # table holds slot-flattened global ids
+    layer: Optional[jax.Array] = None,  # paged: index into stacked pools
     use_rope: bool = True,
     cross: bool = False,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
@@ -178,6 +179,13 @@ def attention_decode(
     ids (``slot * N_pool + page``): a block-table row may then reference
     pages physically owned by another slot — how copy-on-write forks share
     one prompt prefix across N branches.
+
+    ``layer`` (paged only): ``cache`` holds EVERY layer's pools, stacked
+    ``(L, B, N_pool, Hkv, block, D)`` — slot-flattened ``(L, B * N_pool,
+    Hkv, block, D)`` with ``global_pages`` — and this layer's B token rows
+    are written in place at ``[layer, ...]``; the kernel reads the stack
+    by the same index.  The updated stack is returned.  ``None``: the
+    cache is one layer's.
     """
     B, S1, M = x.shape
     assert S1 == 1
@@ -232,31 +240,19 @@ def attention_decode(
             )
             out = jnp.einsum("bhd,hdm->bm", out, p["wo"].astype(dt))
             return out[:, None], dict(cache, k_pool=k_pool, v_pool=v_pool)
-        block = cache["k_pool"].shape[3]
-        barange = jnp.arange(B)
-        offs = lengths % block
+        block = cache["k_pool"].shape[-2]
+        page = block_table[jnp.arange(B), lengths // block]
         with scope("attn.kv_write"):
-            if global_pages:
-                # ---- paged cache, slot-flattened global ids (CoW) ----
-                n_pool = cache["k_pool"].shape[1]
-                page_g = block_table[barange, lengths // block]  # global
-                flat = (B * n_pool,) + cache["k_pool"].shape[2:]
-                # inactive slots' zero rows all land on global page 0
-                # (slot 0's scratch page) — never read, same contract as
-                # the local path's per-slot scratch page
-                k_pool = cache["k_pool"].reshape(flat).at[
-                    page_g, :, offs].set(k1).reshape(cache["k_pool"].shape)
-                v_pool = cache["v_pool"].reshape(flat).at[
-                    page_g, :, offs].set(v1).reshape(cache["v_pool"].shape)
-            else:
-                # ---- paged cache (per-sequence-local pools) ----
-                page = block_table[barange, lengths // block]  # local id
-                k_pool = cache["k_pool"].at[barange, page, :, offs].set(k1)
-                v_pool = cache["v_pool"].at[barange, page, :, offs].set(v1)
+            # inactive slots' zero rows all land on page 0 (with global
+            # ids: slot 0's scratch page) — never read, the contract of
+            # each slot's scratch page
+            k_pool, v_pool = ops.write_kv_rows(
+                cache["k_pool"], cache["v_pool"], k1, v1, page,
+                lengths % block, layer=layer, global_pages=global_pages)
         with scope("attn.kernel"):
             out = ops.paged_attention(
-                q1, k_pool, v_pool, block_table, lengths + 1, n_kv=n_kv,
-                global_pages=global_pages,
+                q1, k_pool, v_pool, block_table, lengths + 1, layer=layer,
+                n_kv=n_kv, global_pages=global_pages,
             )
         new_cache = dict(cache, k_pool=k_pool, v_pool=v_pool)
     elif cfg.sliding_window and cache["k"].shape[1] == cfg.sliding_window:
@@ -298,8 +294,9 @@ def attention_chunk(
     p,
     x: jax.Array,  # (1, C, M) — one prefill chunk for one slot
     cfg: ModelConfig,
-    cache: Dict[str, jax.Array],  # per-layer slice (no leading L dim)
+    cache: Dict[str, jax.Array],  # EVERY layer's pools (leading L dim)
     *,
+    layer: jax.Array,      # scalar int32 — this layer's index in the stack
     slot: jax.Array,       # scalar int32 — the admitting slot
     row: jax.Array,        # (mb,) int32 — block-table row incl. this chunk
     pages: jax.Array,      # (nc,) int32 — pages this chunk writes
@@ -318,9 +315,12 @@ def attention_chunk(
     whole-prompt prefill of the same tokens (asserted in
     tests/test_chunked_prefill.py).
 
-    With ``global_pages`` the ``row``/``pages`` operands carry global ids
-    into the slot-flattened pool (``slot`` is then only the scratch-row
-    owner); writes and the row gather address the flat pool directly.
+    The pools are the whole stack, as in :func:`attention_decode` with a
+    ``layer``: the chunk's pages are written in place at ``[layer, ...]``,
+    the row's pages gathered from there, and the updated stack returned.
+    With ``global_pages`` the pools are slot-flattened ``(L, B * N_pool,
+    Hkv, block, D)`` and ``row``/``pages`` carry global ids (``slot`` is
+    then only the scratch-row owner).
     """
     dt = x.dtype
     scope = jax.named_scope  # the scopes of attention_decode
@@ -328,27 +328,17 @@ def attention_chunk(
         q, k, v = _project_qkv(p, x, cfg)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    block = cache["k_pool"].shape[3]
+    block = cache["k_pool"].shape[-2]
     with scope("attn.kv_write"):
-        kc = to_pages(k[0], block).astype(cache["k_pool"].dtype)
-        vc = to_pages(v[0], block).astype(cache["v_pool"].dtype)
-        if global_pages:
-            flat = (-1,) + cache["k_pool"].shape[2:]
-            kfl = cache["k_pool"].reshape(flat).at[pages].set(kc)
-            vfl = cache["v_pool"].reshape(flat).at[pages].set(vc)
-            kp = kfl.reshape(cache["k_pool"].shape)
-            vp = vfl.reshape(cache["v_pool"].shape)
-        else:
-            kp = cache["k_pool"].at[slot, pages].set(kc)
-            vp = cache["v_pool"].at[slot, pages].set(vc)
+        kp, vp = ops.write_kv_pages(
+            cache["k_pool"], cache["v_pool"], to_pages(k[0], block),
+            to_pages(v[0], block), pages, slot=slot, layer=layer,
+            global_pages=global_pages)
     # the kernel's scope holds the gather of the row's pages it reads
     with scope("attn.kernel"):
-        if global_pages:
-            gk = from_pages(kfl[row[:n_kv]])[None]  # (1, n_kv*bl, Hkv, D)
-            gv = from_pages(vfl[row[:n_kv]])[None]
-        else:
-            gk = from_pages(kp[slot][row[:n_kv]])[None]
-            gv = from_pages(vp[slot][row[:n_kv]])[None]
+        gk, gv = ops.read_kv_pages(kp, vp, row[:n_kv], slot=slot,
+                                   layer=layer, global_pages=global_pages)
+        gk, gv = from_pages(gk)[None], from_pages(gv)[None]
         out = ops.flash_attention(q, gk, gv, causal=True,
                                   q_offset=positions[0])
     out = jnp.einsum("bshd,hdm->bsm", out, p["wo"].astype(dt))

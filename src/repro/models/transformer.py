@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels import ops
 from . import layers as L
 from . import ssm as S
 from .param import ParamSpec
@@ -512,6 +513,44 @@ def _ffn(lp, h, cfg: ModelConfig):
         return L.apply_mlp(lp["mlp"], m_in, cfg)
 
 
+def _decoder_layers(x, params, pools, cfg: ModelConfig, attend, *,
+                    in_place: bool, global_pages: bool):
+    """Run the decoder layers of a serving step over the residual ``x``.
+
+    ``attend(lp, a_in, pools, layer)`` is a layer's attention against
+    the stacked KV cache ``pools`` (``cache["layers"]``); it returns its
+    output and the updated cache.  ``in_place`` (a paged pool): the scan
+    carries the WHOLE stacked pool (slot-flattened once, before the scan,
+    with ``global_pages``) and ``layer`` is the scan's layer index, so no
+    layer's slab is sliced out of the stack or re-stacked after it.
+    Otherwise the cache rides the scan's xs/ys one layer at a time and
+    ``layer`` is None.  Returns (x, new cache)."""
+
+    def layer_fn(h, lp, kv, layer):
+        a, kv = attend(lp, L.apply_norm(lp["norm1"], h, cfg), kv, layer)
+        h = h + a
+        return h + _ffn(lp, h, cfg), kv
+
+    layers = params["layers"]
+    if not in_place:
+        return jax.lax.scan(
+            lambda h, xs: layer_fn(h, xs[0], xs[1], None), x,
+            (layers, pools))
+
+    shape = pools["k_pool"].shape
+    if global_pages:  # (L, B, N, ...) -> (L, B * N, ...): a bitcast
+        pools = jax.tree.map(
+            lambda a: a.reshape((shape[0], -1) + shape[3:]), pools)
+
+    def body(carry, xs):
+        (h, kv), (lp, layer) = carry, xs
+        return layer_fn(h, lp, kv, layer), None
+
+    (x, pools), _ = jax.lax.scan(
+        body, (x, pools), (layers, jnp.arange(shape[0], dtype=jnp.int32)))
+    return x, jax.tree.map(lambda a: a.reshape(shape), pools)
+
+
 def prefill_chunk(params, cache, batch, cfg: ModelConfig, *,
                   n_kv: Optional[int] = None,
                   global_pages: bool = False):
@@ -545,17 +584,15 @@ def prefill_chunk(params, cache, batch, cfg: ModelConfig, *,
     positions = batch["start"] + jnp.arange(C, dtype=jnp.int32)
     x = L.embed_tokens(params["embed"], tokens, cfg)
 
-    def body(h, xs):
-        lp, cl = xs
-        a_in = L.apply_norm(lp["norm1"], h, cfg)
-        a, new_c = L.attention_chunk(
-            lp["attn"], a_in, cfg, cl, slot=slot, row=row, pages=pages,
-            positions=positions, n_kv=n_kv, global_pages=global_pages)
-        h = h + a
-        return h + _ffn(lp, h, cfg), new_c
+    def attend(lp, a_in, pools, layer):
+        return L.attention_chunk(
+            lp["attn"], a_in, cfg, pools, layer=layer, slot=slot, row=row,
+            pages=pages, positions=positions, n_kv=n_kv,
+            global_pages=global_pages)
 
-    x, new_layers = jax.lax.scan(body, x,
-                                 (params["layers"], cache["layers"]))
+    x, new_layers = _decoder_layers(x, params, cache["layers"], cfg, attend,
+                                    in_place=True,
+                                    global_pages=global_pages)
     x = L.apply_norm(params["final_norm"], x, cfg)
     last = jnp.reshape(batch["last_index"], (1, 1, 1)).astype(jnp.int32)
     x_last = jnp.take_along_axis(x, last, axis=1)[:, 0]
@@ -666,18 +703,18 @@ def decode_step(params, cache, batch, cfg: ModelConfig, *,
             new_mc = new_mc_g
         new_cache = dict(cache, layers=new_mc, attn=new_ac)
     else:
-        def body(h, xs):
-            lp, cl = xs
-            a_in = L.apply_norm(lp["norm1"], h, cfg)
-            a, new_c = L.attention_decode(lp["attn"], a_in, cfg, cl,
-                                          lengths, block_table=block_table,
-                                          n_kv=n_kv,
-                                          global_pages=global_pages)
-            h = h + a
-            return h + _ffn(lp, h, cfg), new_c
+        def attend(lp, a_in, pools, layer):
+            return L.attention_decode(lp["attn"], a_in, cfg, pools, lengths,
+                                      block_table=block_table, n_kv=n_kv,
+                                      global_pages=global_pages,
+                                      layer=layer)
 
-        x, new_layers = jax.lax.scan(body, x,
-                                     (params["layers"], cache["layers"]))
+        # the paged pool is updated in place by layer index, unless it is
+        # page-striped over a mesh (the distributed kernel takes a slab)
+        in_place = layout == "paged" and ops.dist_decode_config() is None
+        x, new_layers = _decoder_layers(x, params, cache["layers"], cfg,
+                                        attend, in_place=in_place,
+                                        global_pages=global_pages)
         new_cache = dict(cache, layers=new_layers)
 
     x = L.apply_norm(params["final_norm"], x, cfg)

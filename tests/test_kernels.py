@@ -12,6 +12,9 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.paged_attention import (
     decode_attention_pallas,
     paged_attention_pallas,
+    read_pages_pallas,
+    write_pages_pallas,
+    write_rows_pallas,
 )
 from repro.kernels.ssd_scan import ssd_chunk_scan_pallas
 
@@ -218,6 +221,94 @@ def test_paged_attention_global_pages_cross_slot():
                                  n_kv=mb, global_pages=True, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def stacked_pools(L, B, n_pool, Hkv, block, D, dtype, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (rand(ks[0], (L, B, n_pool, Hkv, block, D), dtype),
+            rand(ks[1], (L, B, n_pool, Hkv, block, D), dtype))
+
+
+def page_table(rs, B, n_pool, mb, global_pages):
+    """Scattered pages: global ids (any slot's page) or each slot's own."""
+    if global_pages:
+        return jnp.asarray(
+            rs.permutation(B * n_pool)[:B * mb].reshape(B, mb), jnp.int32)
+    return jnp.asarray(
+        np.stack([rs.permutation(n_pool)[:mb] for _ in range(B)]), jnp.int32)
+
+
+# (block, D): 128-token pages with a head off the 128 lanes are read in
+# the order HBM holds them, (D, block); 64-token pages as (block, D)
+@pytest.mark.parametrize("block,D", [(128, 64), (64, 64)])
+@pytest.mark.parametrize("global_pages", [True, False])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_attention_stacked_layer(layer, global_pages, block, D):
+    """The kernel reads layer ``layer`` of stacked pools by its index:
+    bit for bit what it reads from ``pool[layer]`` alone, and the oracle
+    with ``layer`` is bit for bit the oracle on ``pool[layer]``."""
+    L, B, n_pool, mb, H, Hkv = 3, 2, 5, 3, 4, 2
+    rs = np.random.RandomState(10 + layer)
+    k_pool, v_pool = stacked_pools(L, B, n_pool, Hkv, block, D,
+                                   jnp.bfloat16, layer)
+    q = rand(jax.random.PRNGKey(20 + layer), (B, H, D), jnp.bfloat16)
+    table = page_table(rs, B, n_pool, mb, global_pages)
+    lengths = jnp.asarray(rs.randint(1, mb * block, (B,)), jnp.int32)
+    kw = dict(global_pages=global_pages)
+    got = paged_attention_pallas(q, k_pool, v_pool, table, lengths,
+                                 layer=jnp.int32(layer), interpret=True, **kw)
+    alone = paged_attention_pallas(q, k_pool[layer], v_pool[layer], table,
+                                   lengths, interpret=True, **kw)
+    want = ref.paged_attention(q, k_pool[layer], v_pool[layer], table,
+                               lengths, **kw)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(alone, np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(ref.paged_attention(q, k_pool, v_pool, table, lengths,
+                                       layer=layer, **kw), np.float32),
+        np.asarray(want, np.float32))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               **TOL[jnp.bfloat16])
+
+
+@pytest.mark.parametrize("block,D", [(128, 64), (64, 64)])
+@pytest.mark.parametrize("global_pages", [True, False])
+def test_pool_writers_and_reader_match_oracle(global_pages, block, D):
+    """The in-place row and page writers and the page reader move the
+    same values to the same places as their oracles: bit for bit, in
+    layer 1 of 3 and nowhere else."""
+    L, B, n_pool, Hkv = 3, 3, 4, 2
+    rs = np.random.RandomState(5)
+    k_pool, v_pool = stacked_pools(L, B, n_pool, Hkv, block, D,
+                                   jnp.bfloat16, 7)
+    kw = dict(layer=jnp.int32(1), global_pages=global_pages)
+    k1 = rand(jax.random.PRNGKey(8), (B, Hkv, D), jnp.bfloat16)
+    v1 = rand(jax.random.PRNGKey(9), (B, Hkv, D), jnp.bfloat16)
+    if global_pages:
+        pages = jnp.asarray(rs.permutation(B * n_pool)[:B], jnp.int32)
+    else:
+        pages = jnp.asarray(rs.randint(0, n_pool, (B,)), jnp.int32)
+    offs = jnp.asarray(rs.randint(0, block, (B,)), jnp.int32)
+    got = write_rows_pallas(k_pool, v_pool, k1, v1, pages, offs,
+                            interpret=True, **kw)
+    want = ref.write_rows(k_pool, v_pool, k1, v1, pages, offs, **kw)
+    for g, w, before in zip(got, want, (k_pool, v_pool)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        np.testing.assert_array_equal(np.asarray(g[0]), np.asarray(before[0]))
+
+    slot = jnp.int32(2)
+    ids = page_table(rs, 1, n_pool, 2, global_pages)[0]
+    kp = rand(jax.random.PRNGKey(11), (2, Hkv, block, D), jnp.bfloat16)
+    vp = rand(jax.random.PRNGKey(12), (2, Hkv, block, D), jnp.bfloat16)
+    got = write_pages_pallas(k_pool, v_pool, kp, vp, ids, slot=slot,
+                             interpret=True, **kw)
+    want = ref.write_pages(k_pool, v_pool, kp, vp, ids, slot=slot, **kw)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    read = read_pages_pallas(*got, ids, slot=slot, interpret=True, **kw)
+    for r, pages_in in zip(read, (kp, vp)):
+        np.testing.assert_array_equal(np.asarray(r), np.asarray(pages_in))
 
 
 # ---------------------------------------------------------------------------

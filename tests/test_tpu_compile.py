@@ -24,6 +24,7 @@ from repro.kernels.paged_attention import (
     paged_attention_pallas,
 )
 from repro.models import Model
+from repro.serving.device_state import DeviceState
 
 H, HKV, D, BLOCK = 14, 2, 64, 128
 SLOTS, MAX_SEQ = 8, 4096
@@ -136,5 +137,45 @@ def test_served_model_entry_compiles_with_kernels(one_chip, entry,
                  "pages": i32((1,)), "last_index": i32(())}
     text = compiled_text(lambda p, c, b: fn(p, c, b)[0], params, cache,
                          batch)
-    assert kernels(text) == {"decode_step": {"paged_attention"},
-                             "prefill_chunk": {"flash_attention"}}[entry]
+    assert kernels(text) == {
+        "decode_step": {"paged_kv_write", "paged_attention"},
+        "prefill_chunk": {"paged_kv_write", "block_gather",
+                          "flash_attention"}}[entry]
+
+
+def test_fused_step_updates_the_pool_in_place(one_chip, monkeypatch):
+    """The engine's fused step at qwen2-0.5b widths, with a prefill chunk
+    riding it at ``n_kv`` 1 (the step that asks for the most
+    temporaries): no ``copy`` or ``broadcast`` has the shape of the
+    stacked pool or of one layer's slab, in either tile order, and the
+    temporaries stay below one K+V pool."""
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", "pallas")
+    model = Model(ARCHS["qwen2-0.5b"])
+    slots = 16
+    cache = jax.eval_shape(lambda: model.init_cache(
+        ShapeConfig("serve", "decode", MAX_SEQ, slots)))
+    dev = DeviceState(model, model.abstract_params(), cache,
+                      max_slots=slots, mb=N_POOL, block=BLOCK,
+                      temperature=0.0, top_p=1.0, chunk_tokens=BLOCK,
+                      global_pages=True)
+    operands = jax.tree.map(
+        lambda x: spec(one_chip, jnp.shape(x), jnp.result_type(x)),
+        dev._operands({}, {}))
+    step = jax.jit(dev._step_fn, donate_argnums=(1, 3, 4, 5, 6, 8),
+                   static_argnums=(29, 30))
+    compiled = step.lower(*operands, 1, True).compile()
+
+    pool = cache["layers"]["k_pool"]
+    L, S, N = pool.shape[:3]
+    tile = pool.shape[3:]
+    stacks = {(L, S, N) + tile, (L, S * N) + tile}
+    slabs = {s[1:] for s in stacks}
+    shapes = {s[:-2] + t for s in stacks | slabs
+              for t in (s[-2:], s[-2:][::-1])}
+    moved = [m.group(0) for m in re.finditer(
+        r"%\S+ = \w+\[([\d,]*)\]\S* (?:copy|broadcast)\(",
+        compiled.as_text())
+        if tuple(int(d) for d in m.group(1).split(",") if d) in shapes]
+    assert moved == []
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize  # K and V
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
