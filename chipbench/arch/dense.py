@@ -1,6 +1,7 @@
-"""Plain float32 reference of the dense decoder the benchmark serves.
+"""The dense pre-norm decoder of Qwen2 and Phi-3: its keys, its work
+counts and its plain float32 reference.
 
-It follows the published equations of Qwen2 and Phi-3 (pre-norm
+The reference follows the published equations of Qwen2 and Phi-3 (pre-norm
 RMSNorm, rotary embedding on rotated halves, grouped-query or multi-head
 causal attention with optional q/k/v biases, SwiGLU MLP, final norm, a
 tied or untied head), in ``jax.numpy`` and float32 under
@@ -29,9 +30,42 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import work
+
+#: no published key beyond the shared ones
+KEYS: Dict[str, str] = {}
+#: the equations below: a SiLU-gated MLP, every layer full attention
+VALUES = {"hidden_act": "silu", "use_sliding_window": False}
+
 Q_BLOCK = 512
 HEAD_ROWS = 256
 F8_MAX = 448.0  # largest finite float8_e4m3fn
+
+
+def init_std(path: str, shape, d_model: int):
+    """Every parameter of the dense decoder has a shared rule."""
+    return None
+
+
+def _head_dim(c: dict) -> int:
+    return c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
+
+
+def shape(c: dict) -> work.Shape:
+    """A token runs through q and o (M x H*D each), k and v (M x K*D
+    each) and the gated MLP's three M x F matrices in every layer."""
+    L, M, F = c["num_hidden_layers"], c["hidden_size"], c["intermediate_size"]
+    H, K, D = (c["num_attention_heads"], c["num_key_value_heads"],
+               _head_dim(c))
+    return work.Shape(layers=L, d_model=M, heads=H, kv_heads=K, head_dim=D,
+                      vocab=c["vocab_size"],
+                      matmul_params=L * (2 * M * H * D + 2 * M * K * D
+                                         + 3 * M * F))
+
+
+def tiny(c: dict) -> dict:
+    """Nothing beyond the shared widths."""
+    return {}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,22 +73,20 @@ class Spec:
     d_model: int
     n_heads: int
     n_kv_heads: int
+    head_dim: int
     d_ff: int
     vocab: int
     eps: float
     theta: float
     tied: bool
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
-
     @classmethod
     def from_config(cls, c: dict) -> "Spec":
         return cls(c["hidden_size"], c["num_attention_heads"],
-                   c["num_key_value_heads"], c["intermediate_size"],
-                   c["vocab_size"], float(c["rms_norm_eps"]),
-                   float(c["rope_theta"]), bool(c["tie_word_embeddings"]))
+                   c["num_key_value_heads"], _head_dim(c),
+                   c["intermediate_size"], c["vocab_size"],
+                   float(c["rms_norm_eps"]), float(c["rope_theta"]),
+                   bool(c["tie_word_embeddings"]))
 
 
 def _qdq(x, axis):

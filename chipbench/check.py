@@ -2,8 +2,9 @@
 
 Once the window has closed, a sample of the requests it finished, drawn
 from the seed and always holding the longest, is run through the plain
-float32 reference (:mod:`chipbench.reference`) over each prompt and its
-served tokens.  The number compared is the widest, over every served
+float32 reference of the configuration's architecture module
+(``chipbench/arch/<bench_arch>.py``) over each prompt and its served
+tokens.  The number compared is the widest, over every served
 token, of the logit gap ``|ref[c] - ref[t]|`` between the served token
 ``t`` and the reference's own choice ``c`` at that position: its
 argmax (greedy traffic), or its draw with the request's own uniform
@@ -106,15 +107,16 @@ def widest(cell, seed: int, served: List[tuple], temperature: float,
            top_p: float, *, precision: str = "f32") -> dict:
     """The cell's number over ``served`` = [(prompt, tokens, key)].
     ``precision="fp8"`` reads the control instead of the program."""
-    import reference
     import weights as W
     from harness import model_config
 
     from repro.models import Model
 
+    arch = cell.arch
     cfg = model_config(cell.config)
-    spec = reference.Spec.from_config(cell.config)
-    w = W.make_weights(Model(cfg).abstract_params(), seed, cfg.d_model)
+    spec = arch.Spec.from_config(cell.config)
+    w = W.make_weights(Model(cfg).abstract_params(), seed, cfg.d_model,
+                       arch.init_std)
     worst, count = 0.0, 0
     for prompt, tokens, key in served:
         if not tokens:
@@ -124,16 +126,15 @@ def widest(cell, seed: int, served: List[tuple], temperature: float,
         P, n = len(prompt), len(tokens)
         seq = prompt + tokens
         rows = np.arange(P - 1, P - 1 + n)
-        ref = reference.logits_at(w, spec, seq, rows, whole_blocks=True)
+        ref = arch.logits_at(w, spec, seq, rows, whole_blocks=True)
         pad = ref.shape[0]
         u = (uniforms(key, P + np.arange(pad)) if temperature > 0
              else np.zeros(pad))
         served = np.zeros(pad, np.int32)
         served[:n] = tokens
         if precision != "f32":
-            ctl = reference.logits_at(w, spec, seq, rows,
-                                      precision=precision,
-                                      whole_blocks=True)
+            ctl = arch.logits_at(w, spec, seq, rows, precision=precision,
+                                 whole_blocks=True)
             served = choose(ctl, jnp.asarray(u, jnp.float32), temperature,
                             top_p)
             del ctl

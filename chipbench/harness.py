@@ -4,7 +4,10 @@ check.  Everything a cell needs is data found by name:
 * ``BENCHMARK.json``: the cell (configuration, traffic, chips) and the
   metrics;
 * ``chipbench/configs/<config>.json``: the model (published keys), its
-  engine settings;
+  engine settings, and ``bench_arch``, which names its architecture's
+  module;
+* ``chipbench/arch/<bench_arch>.py``: the architecture's published keys,
+  initialisers, plain reference and work counts (:mod:`arch`);
 * ``chipbench/traffic/<mix>.json``: the traffic mix, read by
   :mod:`chipbench.traffic`;
 * ``chipbench/cells/<workload>.json``: the cell's offered rate, engine
@@ -21,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import importlib
+import importlib.util
 import json
 import math
 import os
@@ -49,7 +53,11 @@ MODEL_KEYS = {
     "num_key_value_heads": "num_kv_heads", "vocab_size": "vocab_size",
     "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
     "tie_word_embeddings": "tie_embeddings", "torch_dtype": "dtype",
+    "head_dim": "head_dim",
 }
+#: keys of a configuration file that describe the file, not the model
+BOOKKEEPING = {"name", "source", "arch", "bench_arch", "engine", "reduced",
+               "assumed", "departures", "deployment", "replicas", "chips"}
 #: seconds after the window to wait for the first token of a request
 #: that was due inside it; a request without one by then has failed
 GRACE_S = 60.0
@@ -77,6 +85,11 @@ class Cell:
     end_to_end: List[dict]
 
     @property
+    def arch(self):
+        """The configuration's architecture module."""
+        return arch_of(self.config)
+
+    @property
     def engine_kw(self) -> dict:
         kw = dict(self.config["engine"])
         kw.update(self.cell.get("engine", {}))
@@ -95,9 +108,11 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if entry is None:
         raise ValueError(f"no workload {name!r} in BENCHMARK.json")
     cfg = next(c for c in bench["configs"] if c["name"] == entry["config"])
+    config = _json(root / cfg["file"])
+    arch_of(config, cfg["file"])
     return Cell(
         name=name,
-        config=_json(root / cfg["file"]),
+        config=config,
         mix=traffic.load_mix(entry["traffic"]),
         cell=_json(HERE / "cells" / f"{name}.json"),
         chips=entry["chips"],
@@ -108,19 +123,55 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     )
 
 
+def arch_of(config: dict, where: Optional[str] = None):
+    """The architecture module a configuration names (``bench_arch``),
+    once every key the file gives is accounted for: a key the shared
+    ``MODEL_KEYS`` or the module's ``KEYS`` map, a value the module's
+    ``VALUES`` checks, a ``BOOKKEEPING`` key, or a key named under
+    ``assumed``.  ``where`` names the file in a refusal (by default, the
+    configuration's ``name``)."""
+    where = where or config.get("name", "the configuration")
+    name = config.get("bench_arch")
+    if name is None:
+        raise ValueError(f"{where}: no 'bench_arch' (the module under "
+                         f"chipbench/arch/ of its architecture)")
+    if not (isinstance(name, str) and name.isidentifier()
+            and importlib.util.find_spec(f"arch.{name}") is not None):
+        raise ValueError(f"{where}: 'bench_arch' names {name!r}, and there "
+                         f"is no chipbench/arch/{name}.py")
+    arch = importlib.import_module(f"arch.{name}")
+    known = (MODEL_KEYS.keys() | arch.KEYS.keys() | arch.VALUES.keys()
+             | BOOKKEEPING | config.get("assumed", {}).keys())
+    for k in config:
+        if k not in known:
+            raise ValueError(f"{where}: {k!r} is a key that nothing "
+                             f"accounts for: not mapped by the shared keys "
+                             f"or arch/{name}.py, not checked by value, "
+                             f"not named under 'assumed'")
+    for k, want in arch.VALUES.items():
+        if k in config and config[k] != want:
+            raise ValueError(f"{where}: {k} is {config[k]!r}; arch/{name}.py "
+                             f"covers {want!r} only")
+    return arch
+
+
 def model_config(config: dict):
     """The program's ModelConfig for a configuration file: its ``arch``
-    with every published key the file gives; refuses a mismatch."""
+    with every published key the file gives, the shared ones and its
+    architecture module's; refuses a mismatch."""
     from repro.configs import ARCHS
 
-    over = {MODEL_KEYS[k]: v for k, v in config.items() if k in MODEL_KEYS}
+    keys = {**MODEL_KEYS, **arch_of(config).KEYS}
+    over = {keys[k]: v for k, v in config.items() if k in keys}
     cfg = ARCHS[config["arch"]].scaled(**over)
-    for k, field in MODEL_KEYS.items():
+    for k, field in keys.items():
         if k in config and getattr(cfg, field) != config[k]:
             raise ValueError(f"{k}: program runs {getattr(cfg, field)}, "
                              f"the configuration states {config[k]}")
-    if cfg.resolved_head_dim * cfg.num_heads != cfg.d_model:
-        raise ValueError("head_dim is not hidden_size / heads")
+    if ("head_dim" not in config
+            and cfg.resolved_head_dim * cfg.num_heads != cfg.d_model):
+        raise ValueError("head_dim: the configuration gives none, and the "
+                         "program's is not hidden_size / heads")
     return cfg
 
 
@@ -186,7 +237,7 @@ def build_engine(cell: Cell, seed: int, device=None):
     cfg = model_config(cell.config)
     model = Model(cfg)
     params = W.make_weights(model.abstract_params(), seed, cfg.d_model,
-                            device)
+                            cell.arch.init_std, device)
     jax.block_until_ready(params)
     s = cell.mix["sampling"]
     eng = ServingEngine(model, params=params, temperature=s["temperature"],
@@ -449,10 +500,37 @@ class Run:
     shape: work.Shape
     peak: dict
     spans: Any
+    #: the traced window by named scope (scopes.Scoped), for readers of
+    #: device time under a scope of their own
+    scoped: Any = None
 
 
 def load_reader(name: str):
     return importlib.import_module(f"metrics.{name}")
+
+
+def read_trace(trace_dir: str) -> tuple:
+    """``(reduced, scoped)`` of the profile in ``trace_dir``: its ops and
+    idle gaps (trace_reduce) and its device time by named scope
+    (scopes), or ``(None, None)`` where it holds none; the directory is
+    removed."""
+    import glob
+    import shutil
+
+    from jax.profiler import ProfileData
+
+    import scopes
+    from trace_reduce import reduce_planes
+
+    try:
+        files = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+        if not files:
+            return None, None
+        planes = list(ProfileData.from_file(files[0]).planes)
+        return (reduce_planes(planes, HOST_SPANS),
+                scopes.reduce_scopes(planes, scopes.name_stacks(files[0])))
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
 
 
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
@@ -498,17 +576,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     mem = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
               for d in devs)
 
-    reduced = None
+    reduced = scoped = None
     if trace and chips_required:
-        import glob
-        import shutil
-
-        from trace_reduce import reduce_file
-
-        files = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
-        if files:
-            reduced = reduce_file(files[0], HOST_SPANS)
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        reduced, scoped = read_trace(trace_dir)
 
     # the check: a sample of finished requests against the reference,
     # once the program's state is freed
@@ -516,10 +586,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     served = [(list(it.prompt), list(map(int, req.generated)),
                it.sample_key) for it, req, _ in sample]
     spans = eng.spans
-    run = Run(cell, win, probe, reduced,
-              work.Shape.from_config(cell.config),
+    run = Run(cell, win, probe, reduced, cell.arch.shape(cell.config),
               work.peaks(dev.device_kind) if chips_required else
-              {"bf16_flops": 1.0, "hbm_bytes_per_s": 1.0}, spans)
+              {"bf16_flops": 1.0, "hbm_bytes_per_s": 1.0}, spans, scoped)
     layer_values = {}
     if trace:
         for m in cell.per_layer:

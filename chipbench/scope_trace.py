@@ -43,7 +43,6 @@ def main(argv=None) -> int:
     import scopes
     import traffic
     import work
-    from trace_reduce import reduce_file
 
     cell = harness.load_cell(args.workload)
     devs = harness.require_chips(cell.chips)
@@ -59,16 +58,13 @@ def main(argv=None) -> int:
     probe = harness.Probe(eng)
     d = tempfile.mkdtemp(prefix="chipbench-scopes-")
     win = harness.drive(eng, cell, items, args.seconds, clock, probe, d)
-    path = glob.glob(f"{d}/plugins/profile/*/*.xplane.pb")[0]
     if args.keep:
-        shutil.copy(path, args.keep)
-    reduced = reduce_file(path, harness.HOST_SPANS)
-    sc = scopes.reduce_file(path)
-    shutil.rmtree(d, ignore_errors=True)
+        shutil.copy(glob.glob(f"{d}/plugins/profile/*/*.xplane.pb")[0],
+                    args.keep)
+    reduced, sc = harness.read_trace(d)
 
-    run = harness.Run(cell, win, probe, reduced,
-                      work.Shape.from_config(cell.config),
-                      work.peaks(devs[0].device_kind), eng.spans)
+    run = harness.Run(cell, win, probe, reduced, cell.arch.shape(cell.config),
+                      work.peaks(devs[0].device_kind), eng.spans, sc)
     layer = {}
     for m in cell.per_layer:
         v = harness.load_reader(m["name"]).read(run)

@@ -1,5 +1,6 @@
 """A cell cut to a size a CPU test can run: the same code, two layers
-of width 128 and a 512-token vocabulary, four slots, short prompts."""
+of width 128 and a 512-token vocabulary, four slots, short prompts; the
+configuration's architecture module cuts what is its own."""
 
 
 def tiny(cell, dtype="float32", limit=1e-3):
@@ -7,6 +8,9 @@ def tiny(cell, dtype="float32", limit=1e-3):
     c.update(hidden_size=128, intermediate_size=256, num_hidden_layers=2,
              num_attention_heads=4, num_key_value_heads=2, vocab_size=512,
              torch_dtype=dtype)
+    if "head_dim" in c:
+        c["head_dim"] = 32
+    c.update(cell.arch.tiny(c))
     c["engine"].update(max_slots=4, max_seq=512)
     m = cell.mix
     for k in ("prompt", "user"):

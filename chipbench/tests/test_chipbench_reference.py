@@ -10,9 +10,9 @@ import pytest
 import _paths  # noqa: F401
 import check
 import harness
-import reference
 import traffic
 import weights as W
+from arch import dense
 from _tiny import tiny
 
 SEED = 2**32 + 9
@@ -34,11 +34,12 @@ def test_reference_matches_the_programs_forward_pass(name):
     cell = _cell(name)
     cfg = harness.model_config(cell.config)
     model = Model(cfg)
-    w = W.make_weights(model.abstract_params(), SEED, cfg.d_model)
+    w = W.make_weights(model.abstract_params(), SEED, cfg.d_model,
+                       dense.init_std)
     toks = np.random.default_rng(0).integers(1, 512, 300)
     got, _ = model.prefill(w, {"tokens": jnp.asarray(toks)[None]})
-    want = reference.logits_at(w, reference.Spec.from_config(cell.config),
-                               toks, [299])
+    want = dense.logits_at(w, dense.Spec.from_config(cell.config), toks,
+                           [299])
     assert np.abs(np.asarray(got[0]) - np.asarray(want[0])).max() < 1e-4
 
 

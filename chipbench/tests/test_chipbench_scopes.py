@@ -171,7 +171,9 @@ def test_kernel_rooflines_find_their_kernel_by_its_stable_name(
              window=NS(trace_p=(0.0, 1.0)),
              probe=NS(steps=[{"t": 0.5, "ctx": [100, 300],
                               "chunk": (0, 128)}]),
-             shape=work.Shape(2, 896, 14, 2, 64, 4864, 151936),
+             shape=work.Shape(layers=2, d_model=896, heads=14,
+                              kv_heads=2, head_dim=64, vocab=151936,
+                              matmul_params=0),
              peak={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9})
     f, b = (work.paged_attention(run.shape, [100, 300])
             if kernel == "paged_attention" else
@@ -257,3 +259,28 @@ def test_name_stacks_come_from_the_event_metadata():
     assert kernels and all(
         "/step.decode/" in v and "/attn.kernel/" in v
         for v in kernels.values())
+
+
+def test_a_run_keeps_the_traces_scope_reduction_for_readers(tmp_path):
+    """harness.read_trace reduces the profile both ways before the
+    directory goes, so that a reader finds device time under its own
+    scope on ``Run.scoped``."""
+    import shutil
+
+    import harness
+
+    d = tmp_path / "trace"
+    (d / "plugins" / "profile" / "run").mkdir(parents=True)
+    shutil.copy(RECORDED, d / "plugins" / "profile" / "run" / "h.xplane.pb")
+    reduced, scoped = harness.read_trace(str(d))
+    assert not d.exists()
+    want = SC.reduce_file(str(RECORDED))
+    assert scoped.scope_seconds == want.scope_seconds
+    assert scoped.under("mlp") > 0
+    assert reduced.op_seconds == TR.reduce_file(
+        str(RECORDED), harness.HOST_SPANS).op_seconds
+    run = harness.Run(*[None] * 7, scoped=scoped)
+    assert run.scoped.under("step.decode") == want.under("step.decode")
+    (tmp_path / "empty").mkdir()
+    assert harness.read_trace(str(tmp_path / "empty")) == (None, None)
+    assert not (tmp_path / "empty").exists()

@@ -86,7 +86,9 @@ def test_each_roofline_counts_only_its_own_kernel():
              window=NS(trace_p=(0.0, 1.0)),
              probe=NS(steps=[{"t": 0.5, "ctx": [100, 300],
                               "chunk": (0, 128)}]),
-             shape=work.Shape(2, 896, 14, 2, 64, 4864, 151936),
+             shape=work.Shape(layers=2, d_model=896, heads=14,
+                              kv_heads=2, head_dim=64, vocab=151936,
+                              matmul_params=0),
              peak={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9})
     assert kernel_seconds(run, paged.PATTERN) == (pytest.approx(0.008), 2)
     assert kernel_seconds(run, flash.PATTERN) == (pytest.approx(0.006), 1)

@@ -5,10 +5,12 @@ import pytest
 
 import _paths  # noqa: F401
 import work
+from arch import dense
 
 # qwen2-0.5b: 24 layers, d 896, 14 heads, 2 kv heads of 64, ff 4864
-S = work.Shape(layers=24, d_model=896, heads=14, kv_heads=2, head_dim=64,
-               d_ff=4864, vocab=151936)
+S = dense.shape({"num_hidden_layers": 24, "hidden_size": 896,
+                 "num_attention_heads": 14, "num_key_value_heads": 2,
+                 "intermediate_size": 4864, "vocab_size": 151936})
 
 
 def test_paged_attention_by_hand():
@@ -28,7 +30,8 @@ def test_flash_prefill_by_hand():
 
 def test_step_flops_by_hand():
     per_layer = 2 * 896 * 14 * 64 + 2 * 896 * 2 * 64 + 3 * 896 * 4864
-    assert S.layer_matmul_params == per_layer
+    assert S.head_dim == 64
+    assert S.matmul_params == 24 * per_layer
     head = 2 * 896 * 151936
     one = work.step_flops(S, [10])
     assert one == 2 * 24 * per_layer + head + 24 * 4 * 14 * 64 * 10

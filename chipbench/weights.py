@@ -7,7 +7,9 @@ nothing the program made.  Scales keep a random deep model well
 conditioned: every matrix is N(0, 1/fan_in), the head (or the tied
 embedding) has std ``LOGIT_STD / sqrt(d_model)`` so that logits have a
 std of about ``LOGIT_STD``, q/k/v biases N(0, ``BIAS_STD``^2) and norm
-scales 1 + N(0, ``NORM_STD``^2).
+scales 1 + N(0, ``NORM_STD``^2).  The configuration's architecture
+module is asked first (``init_std``), so that it names the scales of
+parameters these rules do not know.
 """
 
 from __future__ import annotations
@@ -30,7 +32,10 @@ def base_key(seed: int):
                               seed >> 32)
 
 
-def _std(path: str, shape, d_model: int) -> float:
+def _std(path: str, shape, d_model: int, init_std) -> float:
+    own = init_std(path, shape, d_model)
+    if own is not None:
+        return own
     name = path.rsplit("/", 1)[-1]
     if path in ("embed/tok", "embed/unembed"):
         return LOGIT_STD / np.sqrt(d_model)
@@ -44,7 +49,9 @@ def _std(path: str, shape, d_model: int) -> float:
         return BIAS_STD
     if name == "scale":
         return NORM_STD
-    raise ValueError(f"no initialiser for parameter {path} {shape}")
+    raise ValueError(f"no initialiser for parameter {path} {shape}: "
+                     f"neither the shared rules nor the architecture "
+                     f"module's init_std name it")
 
 
 def _paths(tree, prefix=""):
@@ -55,8 +62,9 @@ def _paths(tree, prefix=""):
         yield prefix, tree
 
 
-def make_weights(abstract, seed: int, d_model: int, device=None):
-    """``abstract``: the program's parameter tree of ShapeDtypeStructs."""
+def make_weights(abstract, seed: int, d_model: int, init_std, device=None):
+    """``abstract``: the program's parameter tree of ShapeDtypeStructs;
+    ``init_std``: the architecture module's ``init_std``."""
     leaves = list(_paths(abstract))
 
     def build(key):
@@ -64,7 +72,7 @@ def make_weights(abstract, seed: int, d_model: int, device=None):
         for path, a in leaves:
             k = jax.random.fold_in(key, zlib.crc32(path.encode()))
             x = jax.random.normal(k, a.shape, jnp.float32)
-            x = x * _std(path, a.shape, d_model)
+            x = x * _std(path, a.shape, d_model, init_std)
             if path.endswith("scale"):
                 x = x + 1.0
             node = out

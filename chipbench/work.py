@@ -29,26 +29,18 @@ def peaks(device_kind: str) -> dict:
 
 @dataclasses.dataclass(frozen=True)
 class Shape:
+    """The sizes the counts read, made by the configuration's
+    architecture module (``arch.<bench_arch>.shape``)."""
+
     layers: int
     d_model: int
     heads: int
     kv_heads: int
     head_dim: int
-    d_ff: int
     vocab: int
-
-    @classmethod
-    def from_config(cls, c: dict) -> "Shape":
-        return cls(c["num_hidden_layers"], c["hidden_size"],
-                   c["num_attention_heads"], c["num_key_value_heads"],
-                   c["hidden_size"] // c["num_attention_heads"],
-                   c["intermediate_size"], c["vocab_size"])
-
-    @property
-    def layer_matmul_params(self) -> int:
-        M, H, K, D, F = (self.d_model, self.heads, self.kv_heads,
-                         self.head_dim, self.d_ff)
-        return 2 * M * H * D + 2 * M * K * D + 3 * M * F
+    #: matrix parameters one token runs through in all layers, the head
+    #: left out (an expert layer counts the experts a token is routed to)
+    matmul_params: int
 
 
 def paged_attention(s: Shape, contexts: Sequence[int]) -> tuple:
@@ -75,7 +67,7 @@ def step_flops(s: Shape, contexts: Sequence[int], chunk=None) -> int:
     """Model FLOPs of one fused step: a token for every active slot
     (``contexts`` = keys each attends) with its logits, and the chunk's
     valid prompt tokens ``(start, end)`` with the logits of one row."""
-    per_tok = 2 * s.layers * s.layer_matmul_params
+    per_tok = 2 * s.matmul_params
     head = 2 * s.d_model * s.vocab
     f = len(contexts) * (per_tok + head)
     f += s.layers * paged_attention(s, contexts)[0]
